@@ -151,10 +151,10 @@ def run_sharded_service(quick: bool = False, mesh=None):
     to the unsharded row. Rows land in the ``sharded_serving`` section of
     ``BENCH_serving.json``."""
     from repro.core.engine_spec import BankSpec, EngineSpec
-    from repro.launch.mesh import _make_mesh, make_host_mesh
+    from repro.launch.mesh import make_mesh, make_host_mesh
 
     if mesh is None:
-        mesh = (_make_mesh((2, 2), ("data", "model"))
+        mesh = (make_mesh((2, 2), ("data", "model"))
                 if jax.device_count() >= 4 else make_host_mesh())
     cfg = get_config("symbiosis-llama2-13b").reduced(
         n_layers=2, d_model=256 if quick else 512)
@@ -208,7 +208,7 @@ def run_smoke():
 def main():
     import argparse
 
-    from repro.launch.mesh import _make_mesh
+    from repro.launch.mesh import make_mesh
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true")
@@ -218,7 +218,7 @@ def main():
                          "(data, model) device mesh (e.g. --mesh 2 2)")
     args = ap.parse_args()
     if args.mesh:
-        mesh = _make_mesh(tuple(args.mesh), ("data", "model"))
+        mesh = make_mesh(tuple(args.mesh), ("data", "model"))
         run_sharded_service(quick=args.quick, mesh=mesh)
     else:
         run(quick=args.quick)

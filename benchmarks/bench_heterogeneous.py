@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.common.hardware import V5E
 from repro.config import AdapterConfig, TrainConfig
 from repro.configs import get_config
 from repro.serving.kvcache import decode_token_cost, cache_bytes
@@ -26,7 +27,7 @@ def run(quick: bool = False):
     rows = []
     crossover = None
     for ctx in (CONTEXTS[:5] if quick else CONTEXTS):
-        costs = {p: decode_token_cost(cfg, ctx, placement=p)
+        costs = {p: decode_token_cost(cfg, ctx, placement=p, chip=V5E)
                  for p in ("gpu", "gpu_offload", "hetero")}
         row = {"fig": "19", "context": ctx,
                "kv_cache_GB": round(cache_bytes(cfg, ctx) / 1e9, 1)}

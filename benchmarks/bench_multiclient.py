@@ -21,6 +21,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.hardware import V5E
 from repro.config import AdapterConfig, ServeConfig, TrainConfig
 from repro.configs import get_config
 from repro.core import adapters as ad_lib
@@ -179,7 +180,7 @@ def run_paged_admission(quick: bool = False):
 
     def peak_admitted(sc):
         router = PlacementRouter(cfg, [Slot(0, free_hbm=budget)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         eng = ServingEngine(cfg, ACFG, sc, base, bank,
                             max_batch_per_client=max_b, router=router)
         rng = np.random.default_rng(0)
@@ -480,10 +481,10 @@ def run_sharded_serving(quick: bool = False, mesh=None):
     sharded run must be byte-identical (replicated base, client-axis
     partitioning only) and reports its tok/s next to the unsharded row."""
     from repro.core.engine_spec import BankSpec, EngineSpec
-    from repro.launch.mesh import _make_mesh, make_host_mesh
+    from repro.launch.mesh import make_mesh, make_host_mesh
 
     if mesh is None:
-        mesh = (_make_mesh((2, 2), ("data", "model"))
+        mesh = (make_mesh((2, 2), ("data", "model"))
                 if jax.device_count() >= 4 else make_host_mesh())
     cfg = get_config("symbiosis-llama2-13b").reduced(
         n_layers=2, d_model=256 if quick else 512)
@@ -593,7 +594,7 @@ def run_smoke():
 def main():
     import argparse
 
-    from repro.launch.mesh import _make_mesh
+    from repro.launch.mesh import make_mesh
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true")
@@ -603,7 +604,7 @@ def main():
                          "(data, model) device mesh (e.g. --mesh 2 2)")
     args = ap.parse_args()
     if args.mesh:
-        mesh = _make_mesh(tuple(args.mesh), ("data", "model"))
+        mesh = make_mesh(tuple(args.mesh), ("data", "model"))
         run_sharded_serving(quick=args.quick, mesh=mesh)
     else:
         run(quick=args.quick)

@@ -36,7 +36,6 @@ BENCHES = [
     ("fig9_10_memory", "benchmarks.bench_memory"),
     ("fig11_12_multiclient", "benchmarks.bench_multiclient"),
     ("sec5_finetune_service", "benchmarks.bench_finetune_service"),
-    ("fig15_17_sharded", "benchmarks.bench_sharded"),
     ("fig18_19_heterogeneous", "benchmarks.bench_heterogeneous"),
     ("fig21_privacy", "benchmarks.bench_privacy"),
     ("fig22_23_mixed", "benchmarks.bench_mixed"),
@@ -142,6 +141,8 @@ def main():
                     help="persist the serving/paged/compaction sections' rows "
                          "(tok/s, admission counts) as one JSON document")
     args = ap.parse_args()
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import importlib
     if args.smoke:

@@ -1,14 +1,16 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 if "XLA_FLAGS" not in os.environ:               # noqa: E402 — see below
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 """Invariant-checker CLI: ``python -m repro.analysis --all [--json]``.
 
 Runs every pass over the standard hot-path targets (see ``targets.py``)
-and exits nonzero if any error-severity violation survives. The XLA_FLAGS
-line above MUST stay the first statement: jax fixes the device count at
-first initialization, and the collective audit (``--mesh``, included in
-``--all``) compiles the steps under a (data=2, model=2) mesh of host
-devices.
+and exits nonzero if any error-severity violation survives. The lines
+above MUST stay the first statements: jax fixes its platform and device
+count at first initialization, and the collective audit (``--mesh``,
+included in ``--all``) compiles the steps under a (data=2, model=2) mesh
+of host devices. The CPU is pinned so that the checker never takes an
+accelerator the host may have.
 
   --static     donation / poolcopy / moe-remat / frozen-base passes only
   --buckets    engine workload under the trace-count guard only
@@ -47,8 +49,8 @@ def main(argv=None) -> int:
     if args.all or args.buckets:
         results.append(runner.run_buckets())
     if args.all or args.mesh:
-        from repro.launch.mesh import _make_mesh
-        mesh = _make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         results.extend(runner.run_collectives(targets, mesh=mesh))
     if args.all or args.isolation:
         results.extend(runner.run_isolation(targets))

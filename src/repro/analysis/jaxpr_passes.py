@@ -35,7 +35,7 @@ from __future__ import annotations
 
 from typing import Any, Iterator
 
-import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 from repro.analysis.report import PassResult
@@ -53,9 +53,9 @@ def _sub_jaxprs(eqn) -> Iterator[Any]:
     for v in eqn.params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for u in vs:
-            if isinstance(u, jax.core.ClosedJaxpr):
+            if isinstance(u, jex_core.ClosedJaxpr):
                 yield u.jaxpr
-            elif isinstance(u, jax.core.Jaxpr):
+            elif isinstance(u, jex_core.Jaxpr):
                 yield u
 
 
@@ -96,7 +96,7 @@ def check_pool_copies(jaxpr, protected_sigs, *, target: str,
             prim = eqn.primitive.name
             if prim in _BITCAST and any(
                     protected(v) for v in eqn.invars
-                    if not isinstance(v, jax.core.Literal)):
+                    if not isinstance(v, jex_core.Literal)):
                 # the pool under a new layout (layer fold etc.) — protect it
                 for v in eqn.outvars:
                     sig = _var_sig(v)
@@ -149,7 +149,7 @@ def check_pool_copies(jaxpr, protected_sigs, *, target: str,
             for sub in _sub_jaxprs(eqn):
                 walk(sub, depth + 1)
 
-    walk(jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr, 0)
+    walk(jaxpr.jaxpr if isinstance(jaxpr, jex_core.ClosedJaxpr) else jaxpr, 0)
     res.checked["eqns_walked"] = n_eqns
     res.checked["inplace_writes"] = n_inplace
     return res
@@ -180,7 +180,7 @@ def check_moe_checkpointed(jaxpr, *, target: str,
             for sub in _sub_jaxprs(eqn):
                 walk(sub, in_remat or prim in _REMAT)
 
-    walk(jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr, False)
+    walk(jaxpr.jaxpr if isinstance(jaxpr, jex_core.ClosedJaxpr) else jaxpr, False)
     res.checked["top_k_eqns"] = n_topk
     res.checked["remat_regions"] = n_remat
     return res

@@ -150,8 +150,7 @@ def run_collectives(targets=None, *, mesh=None) -> list:
                 base, jax.tree.map(
                     lambda s: jax.sharding.NamedSharding(mesh, s), specs))
             args = (sharded_base,) + tuple(t.args[1:])
-            from repro.launch.mesh import mesh_context
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 hlo = (jax.jit(t.fn, donate_argnums=t.donate_argnums)
                        .lower(*args).compile().as_text())
         results.append(collectives.audit_collectives(

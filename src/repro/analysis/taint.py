@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 from repro.analysis.aliasing import leaf_sig
@@ -57,12 +58,12 @@ def check_frozen_base(fn: Callable, args: tuple, *, base_argnum: int = 0,
     tainted = set(map(id, base_invars))
     for eqn in jaxpr.eqns:
         if any(id(v) in tainted for v in eqn.invars
-               if not isinstance(v, jax.core.Literal)):
+               if not isinstance(v, jex_core.Literal)):
             tainted.update(id(v) for v in eqn.outvars)
 
     base_ids = set(map(id, base_invars))
     for i, v in enumerate(jaxpr.outvars):
-        if isinstance(v, jax.core.Literal) or id(v) in base_ids:
+        if isinstance(v, jex_core.Literal) or id(v) in base_ids:
             continue
         if not hasattr(v.aval, "shape"):
             continue

@@ -1,15 +1,10 @@
 """Soft sharding constraints: no-ops without an ambient mesh.
 
-Model code stays mesh-agnostic — constraints only bind when the launcher
-established a mesh via ``launch.mesh.mesh_context`` (the dry-run /
-production / sharded-engine path); CPU unit tests and single-device runs
-are untouched.
-
-Two ambient-mesh mechanisms exist across jax versions: the abstract mesh
-set by ``jax.set_mesh`` (newer releases) and the legacy resource env bound
-by the ``Mesh`` object's own context manager (this tree's pinned jax).
-``_ambient_mesh`` reads whichever is active, so ``constrain`` binds under
-both.
+Model code stays mesh-agnostic — constraints only bind when the caller
+established a mesh with ``jax.set_mesh`` (the dry-run / production /
+sharded-engine path); CPU unit tests and single-device runs are untouched.
+A constraint that names only axes of the ambient mesh is applied as is: if
+the partitioner refuses it, the error surfaces.
 """
 from __future__ import annotations
 
@@ -18,23 +13,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def _ambient_mesh():
-    """The active mesh (abstract or legacy resource-env), or None."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        try:
-            mesh = get_am()
-        except Exception:
-            mesh = None
-        if mesh is not None and getattr(mesh, "axis_names", None):
-            return mesh
-    try:        # legacy ambient mesh: ``with mesh:`` binds the resource env
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+    """The mesh bound by ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _ambient_axes():
@@ -52,10 +33,7 @@ def constrain(x, *spec):
         for name in ((s,) if isinstance(s, str) else (s or ())):
             if name not in axes:
                 return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def constrain_batch(x, ax: int = 0):

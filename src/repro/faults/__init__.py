@@ -2,7 +2,8 @@
 per-tenant health state machine, deterministic fault injection, and
 conservation audits. The chaos sweep lives in ``repro.faults.chaos``
 (``python -m repro.faults.chaos``)."""
-from repro.faults.health import (FatalFault, HealthPolicy, HealthRecord,
+from repro.faults.health import (AdmissionStall, FatalFault, HealthPolicy,
+                                 HealthRecord,
                                  HealthState, TransientFault, classify)
 from repro.faults.plan import (KINDS, AllocHook, AllocationFault, FaultEvent,
                                FaultPlan, FaultyStream, NonFiniteFault,

@@ -48,6 +48,11 @@ class TransientFault(Exception):
     (the tenant's state is still clean — the fault hit before commit)."""
 
 
+class AdmissionStall(RuntimeError):
+    """An engine holds queued work that can never be admitted: no free
+    capacity, and nothing in flight that could free some."""
+
+
 class FatalFault(Exception):
     """Marker base for faults that immediately quarantine the tenant."""
 

@@ -268,6 +268,7 @@ def paged_decode_attn_pallas(q, pool_k, pool_v, tbl, pos, *, window: int = 0,
         grid_spec=_paged_grid_spec(B, K, G, hd, blk, nb, quant=False),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attn",
     )(tbl.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
 
 
@@ -284,6 +285,7 @@ def paged_decode_attn_quant_pallas(q, pool_k, pool_ks, pool_v, pool_vs, tbl,
         grid_spec=_paged_grid_spec(B, K, G, hd, blk, nb, quant=True),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attn_int8",
     )(tbl.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_ks,
       pool_v, pool_vs)
 

@@ -12,6 +12,9 @@ Dispatch rules (shared by every kernel wrapper in ``repro.kernels``):
   size, so K/V are never re-padded to a block multiple — pads never
   materialize. The dense path picks the largest divisor of T near the
   requested ``block_kv`` before it falls back to zero-padding.
+* Under an ambient mesh the paged op runs per device
+  (``kernels.mesh.per_device``): rows over the batch axes, KV heads over
+  ``model``, whole page pools on every device.
 * ``via_gather=True`` is the TEST ORACLE: it materializes the dense per-row
   view with ``gather_paged_kv`` and runs the same blocked math on it with an
   identity block table — byte-identical to the table-aware read by
@@ -42,6 +45,13 @@ from repro.kernels.decode_attn.decode_attn import (
     paged_decode_attn_quant_stream,
 )
 from repro.kernels.decode_attn.ref import decode_attn_ref, gather_paged_kv, paged_view
+from repro.kernels.mesh import HEADS, ROWS, per_device
+
+# q [B, K, G, hd] and the output: rows and KV heads split; page pools
+# [P, blk, K, hd] split by head (whole pages on every device); tables
+# [B, nb] and positions [B] by row
+_Q = (ROWS, HEADS, None, None)
+_POOL = (None, None, HEADS, None)
 
 
 def backend_interpret() -> bool:
@@ -72,11 +82,11 @@ def _paged_op(window: int, interpret: bool):
 
     @custom_vmap
     def op(q, pool_k, pool_v, tbl, pos):
-        if interpret:
-            return paged_decode_attn_stream(q, pool_k, pool_v, tbl, pos,
-                                            window=window)
-        return paged_decode_attn_pallas(q, pool_k, pool_v, tbl, pos,
-                                        window=window, interpret=False)
+        impl = (functools.partial(paged_decode_attn_stream, window=window)
+                if interpret else
+                functools.partial(paged_decode_attn_pallas, window=window))
+        return per_device(impl, (_Q, _POOL, _POOL, (ROWS, None), (ROWS,)),
+                          _Q, q, pool_k, pool_v, tbl, pos)
 
     @op.def_vmap
     def _rule(axis_size, in_batched, q, pool_k, pool_v, tbl, pos):
@@ -96,13 +106,13 @@ def _paged_op(window: int, interpret: bool):
 def _paged_quant_op(window: int, interpret: bool):
     @custom_vmap
     def op(q, pool_k, pool_ks, pool_v, pool_vs, tbl, pos):
-        if interpret:
-            return paged_decode_attn_quant_stream(q, pool_k, pool_ks, pool_v,
-                                                  pool_vs, tbl, pos,
-                                                  window=window)
-        return paged_decode_attn_quant_pallas(q, pool_k, pool_ks, pool_v,
-                                              pool_vs, tbl, pos,
-                                              window=window, interpret=False)
+        impl = (functools.partial(paged_decode_attn_quant_stream,
+                                  window=window)
+                if interpret else
+                functools.partial(paged_decode_attn_quant_pallas,
+                                  window=window))
+        return per_device(impl, (_Q,) + (_POOL,) * 4 + ((ROWS, None), (ROWS,)),
+                          _Q, q, pool_k, pool_ks, pool_v, pool_vs, tbl, pos)
 
     @op.def_vmap
     def _rule(axis_size, in_batched, q, pool_k, pool_ks, pool_v, pool_vs,

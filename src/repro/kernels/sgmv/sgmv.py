@@ -45,36 +45,6 @@ def _sgmv_kernel(block_adapter,        # scalar-prefetch [nb] int32
         y_ref[...] = jnp.zeros_like(y_ref)
 
 
-def sgmv_pallas(x, A, B, block_adapter, *, block_t: int = 128,
-                block_d: int = 512, scale: float = 1.0,
-                interpret: bool = False):
-    """See ref.sgmv_ref for semantics. Shapes must be pre-padded:
-    T % block_t == 0, dout % block_d == 0."""
-    T, din = x.shape
-    n_adapters, _, r = A.shape
-    dout = B.shape[-1]
-    nb = T // block_t
-    nd = dout // block_d
-    clamped = jnp.clip(block_adapter, 0, n_adapters - 1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb, nd),
-        in_specs=[
-            pl.BlockSpec((block_t, din), lambda i, j, ids: (i, 0)),
-            pl.BlockSpec((1, din, r), lambda i, j, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1, r, block_d), lambda i, j, ids: (ids[i], 0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_t, block_d), lambda i, j, ids: (i, j)),
-    )
-    return pl.pallas_call(
-        functools.partial(_sgmv_kernel, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, dout), x.dtype),
-        interpret=interpret,
-    )(block_adapter, x, A, B)
-
-
 def sgmv_stream(x, A, B, block_adapter, *, block_t: int, scale: float = 1.0):
     """jnp twin of the SGMV kernel: one lax.scan step per token block, each
     step gathering its block's adapter and running the same two
@@ -107,27 +77,22 @@ def sgmv_stream(x, A, B, block_adapter, *, block_t: int, scale: float = 1.0):
     return yb.reshape(T, dout)
 
 
-# NOTE on the index_map trick: clamped ids are NOT what the index_map sees —
-# it receives the raw prefetched table, so callers must pass non-negative ids
-# there when a block is dead but keep the sign bit in the *kernel* table.
-# ``ops.sgmv`` therefore prefetches the raw table (sign used by pl.when) and
-# relies on the index_map clamp below.
-def sgmv_pallas_safe(x, A, B, block_adapter, **kw):
-    """Variant whose index_map clamps dead ids (safe for any input)."""
-    n_adapters = A.shape[0]
+def sgmv_pallas(x, A, B, block_adapter, *, block_t: int = 128,
+                block_d: int = 512, scale: float = 1.0,
+                interpret: bool = False):
+    """See ref.sgmv_ref for semantics. Shapes must be pre-padded:
+    T % block_t == 0, dout % block_d == 0. The kernel body reads the raw
+    prefetched ids (their sign gates dead blocks through ``pl.when``); the
+    index_maps clamp them, so a dead block still names a valid adapter to
+    stream."""
+    T, din = x.shape
+    n_adapters, _, r = A.shape
+    dout = B.shape[-1]
+    nb = T // block_t
+    nd = dout // block_d
 
     def clamp(ids, i):
         return jnp.clip(ids[i], 0, n_adapters - 1)
-
-    T, din = x.shape
-    r = A.shape[-1]
-    dout = B.shape[-1]
-    block_t = kw.get("block_t", 128)
-    block_d = kw.get("block_d", 512)
-    scale = kw.get("scale", 1.0)
-    interpret = kw.get("interpret", False)
-    nb = T // block_t
-    nd = dout // block_d
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -144,4 +109,5 @@ def sgmv_pallas_safe(x, A, B, block_adapter, **kw):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, dout), x.dtype),
         interpret=interpret,
+        name="sgmv",
     )(block_adapter, x, A, B)

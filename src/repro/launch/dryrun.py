@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run (deliverable e).
 
@@ -9,9 +10,10 @@ under the single-pod (16×16) and multi-pod (2×16×16) meshes, print
 (including collective-traffic accounting parsed from the partitioned HLO)
 to ``experiments/dryrun/``.
 
-The two lines above MUST stay the first statements in this module: jax
-fixes the device count at first initialization, and only the dry-run wants
-512 placeholder CPU devices.
+The lines above MUST stay the first statements in this module: jax fixes
+its platform and device count at first initialization, and the dry-run
+wants 512 placeholder CPU devices. It pins the CPU so that it never takes
+an accelerator the host may have.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k
@@ -27,7 +29,7 @@ import jax
 from repro.config import SHAPES
 from repro.configs import ARCHS, ASSIGNED, get_config
 from repro.launch import hlo_analysis, specs
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.common.tree import tree_bytes
 
 
@@ -57,7 +59,7 @@ def run_one(arch: str, shape: str, *, multi_pod: bool = False,
     donate = (1, 2) if shape == "train_4k" else (2,)
     # The ambient mesh makes the soft sharding constraints in model
     # code (repro.common.constrain) bind to the production mesh.
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(bundle.fn, donate_argnums=donate).lower(*bundle.args)
         t_lower = time.time() - t0
         t0 = time.time()
@@ -84,8 +86,6 @@ def run_one(arch: str, shape: str, *, multi_pod: bool = False,
     cost = {}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0]
         cost = {k: float(v) for k, v in ca.items()
                 if isinstance(v, (int, float)) and (
                     k in ("flops", "transcendentals") or "bytes" in k)}
@@ -171,7 +171,7 @@ def run_service(arch: str, *, n_jobs: int = 20, seq_len: int = 4096,
     bundle = specs.service_specs(arch, mesh, n_jobs=n_jobs, batch=batch,
                                  seq_len=seq_len,
                                  replicate_base=replicate_base)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(bundle.fn, donate_argnums=(1, 2)).lower(*bundle.args)
         t_lower = time.time() - t0
         t0 = time.time()

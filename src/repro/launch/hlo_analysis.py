@@ -394,8 +394,8 @@ class Roofline:
 
     @property
     def collective_s(self) -> float:
-        # v5e 2D torus: traffic spreads over the chip's usable ICI links.
-        return self.coll_bytes / (self.chip.ici_link_bandwidth * self.chip.ici_links)
+        # all of the chip's ICI links together
+        return self.coll_bytes / self.chip.ici_bandwidth
 
     @property
     def dominant(self) -> str:

@@ -10,35 +10,22 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist in newer releases; older ones
-    default every axis to Auto, which is exactly what we want."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """A device mesh over ``jax.devices()`` whose axes are all Auto, so the
+    partitioner places what sharding constraints leave open."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-chip mesh with the production axis names (CPU smoke tests)."""
-    return _make_mesh((1, 1), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` across jax versions: releases without it fall
-    back to the ``Mesh`` object's own context manager (the legacy ambient
-    mesh), which is what ``with_sharding_constraint`` binds to there."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

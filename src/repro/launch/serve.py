@@ -1,8 +1,11 @@
 """Multi-tenant serving driver (deliverable b).
 
 Serves a bank of adapter clients against one shared base with the
-ServingEngine (opportunistic batching). Reduced configs run real tokens on
-CPU; full configs target the production mesh (proven by dryrun.py).
+ServingEngine (opportunistic batching). By default it runs a reduced
+config (2 layers, d_model 256), small enough for the CPU; ``--full-size``
+runs the published config on the accelerator. ``chip_smoke.py`` at the
+repository root is the end-to-end check that full-size serving runs on a
+TPU v5e.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b \
@@ -16,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.config import AdapterConfig, ServeConfig
 from repro.configs import ARCHS, get_config
 from repro.core import symbiosis
@@ -26,8 +30,8 @@ from repro.serving.engine import ServingEngine, Request
 def _mesh_from(dims):
     if dims is None:
         return None
-    from repro.launch.mesh import _make_mesh
-    return _make_mesh(tuple(dims), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh(tuple(dims), ("data", "model"))
 
 
 def main(argv=None):
@@ -59,6 +63,7 @@ def main(argv=None):
                     help="attach telemetry (docs/observability.md) and write "
                          "telemetry.jsonl + metrics.prom into DIR at exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size:

@@ -1,11 +1,12 @@
 """Multi-job fine-tuning driver — a thin wrapper over the FinetuneEngine
 (fine-tuning as a service; deliverable b).
 
-On this CPU container it trains REDUCED variants of any assigned arch for
-real steps (loss decreases); on TPU hardware the same driver lowers the
-full config onto the production mesh (the mesh/sharding path is proven by
-``dryrun.py``). ``--peft mixed`` cycles LoRA / IA3 / prefix across jobs —
-heterogeneous banks sharing one engine and one base.
+By default it trains a REDUCED variant of the chosen arch (small enough
+for the CPU) for real steps; ``--full-size`` trains the published config
+on the accelerator (``chip_smoke.py`` at the repository root is the
+end-to-end check that full-size fine-tuning runs on a TPU v5e).
+``--peft mixed`` cycles LoRA / IA3 / prefix across jobs — heterogeneous
+banks sharing one engine and one base.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --clients 4 \
@@ -20,6 +21,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.config import AdapterConfig, FinetuneConfig
 from repro.configs import ARCHS, get_config
 from repro.checkpoint import save_job_state
@@ -57,6 +59,7 @@ def main(argv=None):
                     help="attach telemetry (docs/observability.md) and write "
                          "telemetry.jsonl + metrics.prom into DIR at exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_size:
@@ -66,8 +69,8 @@ def main(argv=None):
     base = get_model(cfg).init_params(key)
     mesh = None
     if args.mesh:
-        from repro.launch.mesh import _make_mesh
-        mesh = _make_mesh(tuple(args.mesh), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(tuple(args.mesh), ("data", "model"))
     fcfg = FinetuneConfig(max_jobs=args.clients,
                           memory_optimized=not args.no_memory_optimized)
     spec = EngineSpec(cfg=cfg, finetune=fcfg, mesh=mesh,

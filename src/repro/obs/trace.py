@@ -21,10 +21,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-try:  # pragma: no cover - import guard, exercised implicitly
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -39,17 +36,15 @@ class Span:
         self._ann = None
 
     def __enter__(self) -> "Span":
-        if _TraceAnnotation is not None:
-            self._ann = _TraceAnnotation(f"repro.obs/{self.name}")
-            self._ann.__enter__()
+        self._ann = TraceAnnotation(f"repro.obs/{self.name}")
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dt = time.perf_counter() - self._t0
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
-            self._ann = None
+        self._ann.__exit__(exc_type, exc, tb)
+        self._ann = None
         self._hist.observe(dt)
         return False
 

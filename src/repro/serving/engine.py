@@ -169,7 +169,8 @@ from repro.core import adapters as adapters_lib
 from repro.core import symbiosis
 from repro.core.engine_spec import EngineSpec
 from repro.core.scheduler import ClientSpec, TickPolicy, simulate
-from repro.faults.health import HealthPolicy, HealthRecord, HealthState, classify
+from repro.faults.health import (AdmissionStall, HealthPolicy, HealthRecord,
+                                 HealthState, classify)
 from repro.faults.plan import TransientFault
 from repro.serving.prefix_cache import PrefixIndex, sharable_tokens
 
@@ -839,7 +840,7 @@ class ServingEngine:
             # nothing in flight to ever free capacity, and admission of
             # every due request just failed -> stuck forever (an injected
             # transient admission fault is NOT stuck: the retry may succeed)
-            raise RuntimeError(
+            raise AdmissionStall(
                 f"{len(attempted)} request(s) can never be admitted "
                 f"(no free capacity and nothing in flight)")
         tick += 1
@@ -1366,8 +1367,7 @@ class ServingEngine:
         path (``common.constrain``) resolve; a no-op single-device."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.launch.mesh import mesh_context
-        return mesh_context(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _place_on_mesh(self):
         """``device_put`` the engine's mutable state onto the mesh: caches

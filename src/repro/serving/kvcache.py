@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig, RWKV, HYBRID, ENCDEC
-from repro.common.hardware import V5E
+from repro.common.hardware import Chip
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,14 +76,6 @@ def make_cache_spec(cfg: ModelConfig, *, quant: bool = False) -> CacheSpec:
         return CacheSpec("encdec", cfg.n_layers * kv_row, fixed)
     per_tok = cfg.n_layers * kv_row
     return CacheSpec("kv", per_tok, 0)
-
-
-def fits_hbm(cfg: ModelConfig, seq_len: int, batch: int, *, chip=V5E,
-             reserved_fraction: float = 0.35) -> bool:
-    """Admission check: does this client's cache fit beside its share of the
-    base? ``reserved_fraction`` approximates base weights + activations."""
-    spec = make_cache_spec(cfg)
-    return spec.total_bytes(seq_len, batch) < chip.hbm_bytes * (1 - reserved_fraction)
 
 
 # ---------------------------------------------------------------------------
@@ -137,7 +129,7 @@ class PlacementCost:
 
 
 def decode_token_cost(cfg: ModelConfig, seq_len: int, *, placement: str,
-                      chip=V5E) -> PlacementCost:
+                      chip: Chip) -> PlacementCost:
     """Analytic per-token decode cost for the §3.4 placements.
 
     placement:

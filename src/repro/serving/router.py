@@ -26,7 +26,7 @@ import dataclasses
 from typing import List, Optional
 
 from repro.config import ModelConfig
-from repro.common.hardware import V5E, Chip
+from repro.common.hardware import Chip, chip_for
 from repro.serving.kvcache import cache_bytes, decode_token_cost
 
 
@@ -35,7 +35,6 @@ class Slot:
     """One accelerator's client-side capacity (base executor excluded)."""
     slot_id: int
     free_hbm: float
-    chip: Chip = V5E
 
     def fits(self, nbytes: float) -> bool:
         return nbytes <= self.free_hbm
@@ -53,8 +52,13 @@ class PlacementRouter:
     """Routes client sessions onto a fleet of accelerator slots + CPU hosts."""
 
     def __init__(self, cfg: ModelConfig, slots: List[Slot],
-                 *, host_free_bytes: float = 400e9):
+                 *, chip: Optional[Chip] = None,
+                 host_free_bytes: float = 400e9):
+        """``chip`` prices the placements; None takes the table entry of
+        the local device (``common.hardware.chip_for``), which is an error
+        on a device the table does not know."""
         self.cfg = cfg
+        self.chip = chip or chip_for()
         self.slots = {s.slot_id: s for s in slots}
         self.host_free = host_free_bytes
         # conservation ledger (docs/robustness.md): initial capacities plus
@@ -83,9 +87,12 @@ class PlacementRouter:
                            quant=quant)
         candidates = []
 
-        gpu = decode_token_cost(self.cfg, context_len, placement="gpu")
-        off = decode_token_cost(self.cfg, context_len, placement="gpu_offload")
-        het = decode_token_cost(self.cfg, context_len, placement="hetero")
+        gpu = decode_token_cost(self.cfg, context_len, placement="gpu",
+                                chip=self.chip)
+        off = decode_token_cost(self.cfg, context_len,
+                                placement="gpu_offload", chip=self.chip)
+        het = decode_token_cost(self.cfg, context_len, placement="hetero",
+                                chip=self.chip)
 
         for s in self.slots.values():
             if gpu.total != float("inf") and s.fits(need):

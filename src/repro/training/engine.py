@@ -77,7 +77,8 @@ from repro.config import AdapterConfig, FinetuneConfig, ModelConfig
 from repro.core import adapters as adapters_lib
 from repro.core import symbiosis
 from repro.core.engine_spec import EngineSpec
-from repro.faults.health import HealthPolicy, HealthRecord, classify
+from repro.faults.health import (AdmissionStall, HealthPolicy, HealthRecord,
+                                 classify)
 from repro.faults.plan import NonFiniteFault, StreamExhausted, TransientFault
 from repro.optim import adamw_init
 from repro.training.job import FinetuneJob, JobResult
@@ -428,8 +429,7 @@ class FinetuneEngine:
         constraints inside the hot path (``common.constrain``) resolve."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.launch.mesh import mesh_context
-        return mesh_context(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _place_bank(self, bank: _Bank):
         """``device_put`` a bank's stacked params/opt onto the mesh (slot
@@ -649,7 +649,7 @@ class FinetuneEngine:
             obs.metrics.counter("train_backoff_skips_total").inc(backing_off)
         if self._queue and not self._slot_of and not admitted_any \
                 and not self._admission_faulted and not backing_off:
-            raise RuntimeError(
+            raise AdmissionStall(
                 f"{len(self._queue)} job(s) can never be admitted "
                 f"(no free capacity and nothing running)")
         for bank in self._banks.values():

@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 
 from repro.core.engine_spec import EngineSpec
+from repro.faults.health import AdmissionStall
 from repro.serving.engine import Request, ServingEngine
 from repro.training.engine import FinetuneEngine
 from repro.training.job import FinetuneJob
@@ -101,19 +102,22 @@ class SymbiosisEngine:
         ``train_every`` train ticks (if jobs exist). Returns True while
         either engine still has work.
 
-        Each engine's standalone stuck detection ("can never be admitted")
-        assumes nothing outside itself will ever free capacity. Under a
+        Each engine's standalone stuck detection (``AdmissionStall``: "can
+        never be admitted") assumes nothing outside itself will ever free
+        capacity. Under a
         SHARED PlacementRouter that assumption is wrong in exactly this
         configuration — a queued request may be waiting on HBM pinned by a
         fine-tuning job (or vice versa) — so a stall in one engine is
-        fatal only when the OTHER engine holds nothing that could free."""
+        fatal only when the OTHER engine holds nothing that could free.
+        Every other error, a device or compile error among them,
+        propagates."""
         did = False
         if self.serving is not None and self.serving.pending():
             try:
                 self.serving.service_tick()
                 self.stats["decode_ticks"] += 1
                 did = True
-            except RuntimeError:
+            except AdmissionStall:
                 if not (self.finetune is not None and self.finetune.n_active):
                     raise          # nothing training-side will ever free
                 self.stats["admission_stalls"] += 1
@@ -123,7 +127,7 @@ class SymbiosisEngine:
                     self.finetune.train_tick()
                     self.stats["train_ticks"] += 1
                     did = True
-                except RuntimeError:
+                except AdmissionStall:
                     if not (self.serving is not None
                             and self.serving.n_inflight):
                         raise      # nothing serving-side will ever free

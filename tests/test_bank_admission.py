@@ -12,6 +12,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.common.hardware import V5E
 from repro.config import AdapterConfig, ServeConfig, DENSE
 from repro.core import adapters as ad_lib
 from repro.core import symbiosis
@@ -135,7 +136,7 @@ def test_retire_refuses_busy_clients_and_router_roundtrip():
 
     cfg = tiny(DENSE)
     base, bank, _ = symbiosis.init_system(cfg, LORA, 1, jax.random.PRNGKey(6))
-    router = PlacementRouter(cfg, [Slot(0, free_hbm=1e9)], host_free_bytes=0)
+    router = PlacementRouter(cfg, [Slot(0, free_hbm=1e9)], chip=V5E, host_free_bytes=0)
     eng = ServingEngine(cfg, LORA, ServeConfig(n_clients=1, max_seq=32,
                                                page_block=8),
                         base, bank, max_batch_per_client=1, router=router)

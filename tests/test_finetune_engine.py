@@ -11,6 +11,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.common.hardware import V5E
 from repro.config import AdapterConfig, FinetuneConfig, ServeConfig, TrainConfig
 from repro.core import adapters as ad_lib
 from repro.core import symbiosis
@@ -306,7 +307,7 @@ class TestAdmission:
         probe = _job(cfg, "lora", seed=0, steps=2)
         nbytes = job_hbm_bytes(cfg, probe)
         router = PlacementRouter(cfg, [Slot(0, free_hbm=nbytes * 1.5)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         eng = FinetuneEngine(cfg, base, router=router)
         eng.submit(_job(cfg, "lora", seed=0, steps=2))
         eng.submit(_job(cfg, "lora", seed=1, steps=2))
@@ -319,7 +320,7 @@ class TestAdmission:
     def test_unadmittable_job_raises(self, base):
         cfg = tiny()
         router = PlacementRouter(cfg, [Slot(0, free_hbm=16.0)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         eng = FinetuneEngine(cfg, base, router=router)
         eng.submit(_job(cfg, "lora", seed=0, steps=2))
         with pytest.raises(RuntimeError, match="never be admitted"):
@@ -417,7 +418,7 @@ class TestSymbiosisService:
         # fits the training job OR one request, never both
         router = PlacementRouter(
             cfg, [Slot(0, free_hbm=max(req_need, job_need) * 1.2)],
-            host_free_bytes=0)
+            chip=V5E, host_free_bytes=0)
         serving = ServingEngine(cfg, acfg, scfg, base, bank,
                                 max_batch_per_client=1, router=router)
         ft = FinetuneEngine(cfg, base, router=router)
@@ -437,7 +438,7 @@ class TestSymbiosisService:
         solo = ServingEngine(cfg, acfg, scfg, base, bank,
                              max_batch_per_client=1,
                              router=PlacementRouter(cfg, [Slot(0, free_hbm=16.0)],
-                                                    host_free_bytes=0))
+                                                    chip=V5E, host_free_bytes=0))
         solo.submit(self._requests(cfg)[0])
         with pytest.raises(RuntimeError, match="never be admitted"):
             solo.run()

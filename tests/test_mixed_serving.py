@@ -13,6 +13,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.common.hardware import V5E
 from repro.config import AdapterConfig, ServeConfig, DENSE, MOE, VLM, HYBRID, ENCDEC
 from repro.core import adapters as ad_lib
 from repro.models import get_model
@@ -144,7 +145,7 @@ class TestMixedMethodEngine:
         bank_bytes = [ad_lib.adapter_bytes(cfg, a)[1] for a in METHOD_CFGS]
         budget = kvcache.cache_bytes(cfg, scfg.max_seq, 6) + sum(bank_bytes) * 2
         router = PlacementRouter(cfg, [Slot(0, free_hbm=budget)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         eng = ServingEngine(cfg, METHOD_CFGS, scfg, base, banks,
                             max_batch_per_client=2, router=router)
         assert len(eng._bank_placements) == 3
@@ -161,7 +162,7 @@ class TestMixedMethodEngine:
         bank_bytes = [ad_lib.adapter_bytes(cfg, a)[1] for a in METHOD_CFGS]
         budget = sum(bank_bytes[:2]) + bank_bytes[2] * 0.5   # 3rd won't fit
         router = PlacementRouter(cfg, [Slot(0, free_hbm=budget)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         with pytest.raises(RuntimeError, match="serving-bank"):
             ServingEngine(cfg, METHOD_CFGS, scfg, base, banks,
                           max_batch_per_client=2, router=router)

@@ -28,7 +28,7 @@ from repro.kernels.decode_attn.decode_attn import (
 from repro.kernels.decode_attn.ops import decode_attn, _dense_block_kv
 from repro.kernels.decode_attn.ref import decode_attn_ref
 from repro.kernels.sgmv.ops import sgmv
-from repro.kernels.sgmv.sgmv import sgmv_pallas_safe, sgmv_stream
+from repro.kernels.sgmv.sgmv import sgmv_pallas, sgmv_stream
 
 
 def _paged_case(B, K, G, hd, P, blk, nb, seed=0, quant=False):
@@ -159,7 +159,7 @@ class TestSgmvContracts:
         A = jax.random.normal(ks[1], (n, din, r)) * 0.3
         B = jax.random.normal(ks[2], (n, r, dout)) * 0.3
         ids = jnp.array([0, -1], jnp.int32)
-        a = jax.jit(lambda *t: sgmv_pallas_safe(*t, block_t=128, block_d=128,
+        a = jax.jit(lambda *t: sgmv_pallas(*t, block_t=128, block_d=128,
                                                 scale=0.5, interpret=True))(
             x, A, B, ids)
         b = jax.jit(lambda *t: sgmv_stream(*t, block_t=128, scale=0.5))(

@@ -1,6 +1,7 @@
 """Placement router (§3.4 provider-side decisions)."""
 import pytest
 
+from repro.common.hardware import V5E
 from repro.configs import get_config
 from repro.serving.router import PlacementRouter, Slot
 
@@ -8,7 +9,7 @@ from repro.serving.router import PlacementRouter, Slot
 @pytest.fixture
 def router():
     cfg = get_config("symbiosis-llama2-13b")
-    return PlacementRouter(cfg, [Slot(0, free_hbm=10e9), Slot(1, free_hbm=10e9)])
+    return PlacementRouter(cfg, [Slot(0, free_hbm=10e9), Slot(1, free_hbm=10e9)], chip=V5E)
 
 
 class TestRouting:
@@ -51,7 +52,7 @@ class TestRouting:
         need = cache_bytes(cfg, 4_000, batch=4)
         # a slot that fits the true batch-4 footprint but not 4x it
         r = PlacementRouter(cfg, [Slot(0, free_hbm=need * 1.5)],
-                            host_free_bytes=0)
+                            chip=V5E, host_free_bytes=0)
         p = r.route(context_len=4_000, batch=4)
         assert p.mode == "gpu" and p.cache_bytes == need
 
@@ -72,6 +73,23 @@ class TestRouting:
 
     def test_oom_raises(self):
         cfg = get_config("symbiosis-llama2-13b")
-        r = PlacementRouter(cfg, [Slot(0, free_hbm=1e9)], host_free_bytes=1e9)
+        r = PlacementRouter(cfg, [Slot(0, free_hbm=1e9)], chip=V5E, host_free_bytes=1e9)
         with pytest.raises(RuntimeError):
             r.route(context_len=500_000)
+
+
+class TestChipTable:
+    def test_router_prices_the_local_chip_or_refuses(self):
+        """Without an explicit chip the router takes the local device's
+        table entry; the CPU has none, so that is an error, not a v5e."""
+        cfg = get_config("symbiosis-llama2-13b")
+        with pytest.raises(ValueError, match="no hardware entry"):
+            PlacementRouter(cfg, [Slot(0, free_hbm=1e9)])
+
+    def test_chip_table_is_keyed_by_device_kind(self):
+        from types import SimpleNamespace
+
+        from repro.common.hardware import chip_for
+        assert chip_for(SimpleNamespace(device_kind="TPU v5 lite")) is V5E
+        with pytest.raises(ValueError):
+            chip_for(SimpleNamespace(device_kind="TPU v4"))

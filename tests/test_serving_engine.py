@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.common.hardware import V5E
 from repro.config import ServeConfig, DENSE
 from repro.core import symbiosis
 from repro.serving.engine import ServingEngine, Request, SamplingParams
@@ -187,7 +188,7 @@ class TestContinuousBatching:
         cfg, scfg, base, bank = system
         need = kvcache.cache_bytes(cfg, scfg.max_seq, 1)
         router = PlacementRouter(cfg, [Slot(0, free_hbm=need * 1.5)],
-                                 host_free_bytes=0)
+                                 chip=V5E, host_free_bytes=0)
         eng = ServingEngine(cfg, lora_cfg, scfg, base, bank,
                             max_batch_per_client=1, router=router)
         rng = np.random.default_rng(5)
@@ -334,12 +335,12 @@ class TestPagedServing:
         eng_d, done_d = self._run(
             cfg, scfg, base, bank, lora_cfg, reqs(), max_b=1,
             router=PlacementRouter(cfg, [Slot(0, free_hbm=budget)],
-                                   host_free_bytes=0))
+                                   chip=V5E, host_free_bytes=0))
         scfg_paged = dataclasses.replace(scfg, page_block=16)
         eng_p, done_p = self._run(
             cfg, scfg_paged, base, bank, lora_cfg, reqs(), max_b=1,
             router=PlacementRouter(cfg, [Slot(0, free_hbm=budget)],
-                                   host_free_bytes=0))
+                                   chip=V5E, host_free_bytes=0))
         assert len(done_d) == len(done_p) == 3
         assert eng_d.stats["peak_inflight"] == 1   # dense: serialized by HBM
         assert eng_p.stats["peak_inflight"] == 3   # paged: all fit at once
@@ -404,11 +405,11 @@ class TestCacheSpec:
         """Fig 19's shape: hetero beats gpu_offload beyond some context."""
         from repro.configs import get_config
         cfg = get_config("symbiosis-llama2-13b")
-        short = kvcache.decode_token_cost(cfg, 2_000, placement="gpu")
-        short_h = kvcache.decode_token_cost(cfg, 2_000, placement="hetero")
-        long = kvcache.decode_token_cost(cfg, 131_072, placement="gpu_offload")
-        long_g = kvcache.decode_token_cost(cfg, 131_072, placement="gpu")
-        long_h = kvcache.decode_token_cost(cfg, 131_072, placement="hetero")
+        short = kvcache.decode_token_cost(cfg, 2_000, placement="gpu", chip=V5E)
+        short_h = kvcache.decode_token_cost(cfg, 2_000, placement="hetero", chip=V5E)
+        long = kvcache.decode_token_cost(cfg, 131_072, placement="gpu_offload", chip=V5E)
+        long_g = kvcache.decode_token_cost(cfg, 131_072, placement="gpu", chip=V5E)
+        long_h = kvcache.decode_token_cost(cfg, 131_072, placement="hetero", chip=V5E)
         assert short.total < short_h.total, "all-GPU wins short contexts"
         assert long_g.total == float("inf"), "all-GPU OOMs at 131k (Fig 19)"
         assert long_h.total < long.total, "hetero must win long contexts"

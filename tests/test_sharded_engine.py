@@ -24,7 +24,7 @@ from repro.config import (AdapterConfig, FinetuneConfig, ServeConfig,
                           DENSE, MOE)
 from repro.core import symbiosis
 from repro.core.engine_spec import BankSpec, EngineSpec
-from repro.launch.mesh import _make_mesh, make_host_mesh
+from repro.launch.mesh import make_mesh, make_host_mesh
 from repro.serving.engine import Request, ServingEngine
 from repro.training.engine import FinetuneEngine
 from repro.training.job import FinetuneJob, make_job_stream
@@ -162,7 +162,7 @@ def test_serving_identity_2x2(method):
     cfg = tiny(DENSE)
     acfg = METHODS[method]
     base, bank, _ = symbiosis.init_system(cfg, acfg, 2, jax.random.PRNGKey(0))
-    mesh = _make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     keep = []
     ref = _serve_stream(cfg, acfg, base, bank, None, keep=keep)
     got = _serve_stream(cfg, acfg, base, bank, mesh, replicate_base=True,
@@ -180,7 +180,7 @@ def test_train_identity_2x2():
     cfg = tiny(DENSE)
     acfg = METHODS["lora"]
     base = symbiosis.init_system(cfg, acfg, 1, jax.random.PRNGKey(0))[0]
-    mesh = _make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     keep = []
     ref = _train_result(cfg, acfg, base, None, n_jobs=2, keep=keep)
     got = _train_result(cfg, acfg, base, mesh, replicate_base=True, n_jobs=2,
