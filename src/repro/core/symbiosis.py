@@ -45,6 +45,15 @@ from repro.optim import adamw_init, adamw_update, adamw_update_hyper
 from repro.optim.schedules import warmup_cosine
 
 
+def program_named(fn, name: str):
+    """Name a step function for ``jax.jit``: its program then carries
+    ``jit_<name>`` on the device (a profiler trace's ``XLA Modules`` line),
+    whichever closure the step function's maker returned. The computation
+    is untouched."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 # ---------------------------------------------------------------------------
 # State construction
 # ---------------------------------------------------------------------------
@@ -336,7 +345,10 @@ def make_compact_train_step(cfg: ModelConfig, acfg: AdapterConfig, *,
         return new_bank, new_opt, {"loss": losses, "gnorm": gnorms, "lr": lr,
                                    "finite": finite}
 
-    return train_step
+    # train_lora_r16, train_ia3, train_prefix: the banks of one engine are
+    # told apart on the device by method and LoRA rank
+    return program_named(train_step, f"train_lora_r{acfg.rank}"
+                         if acfg.method == "lora" else f"train_{acfg.method}")
 
 
 # ---------------------------------------------------------------------------
@@ -791,7 +803,8 @@ def make_compact_decode_step(cfg: ModelConfig, acfg, scfg: ServeConfig,
         return _out(constrain_batch(logits),
                     dict(new_inner, block_tbl=caches["block_tbl"]))
 
-    return compact_mixed if mixed else compact
+    return program_named(compact_mixed if mixed else compact,
+                         "compact_decode")
 
 
 def make_compact_prefill(cfg: ModelConfig, acfg, scfg: ServeConfig,
@@ -926,7 +939,8 @@ def make_compact_prefill(cfg: ModelConfig, acfg, scfg: ServeConfig,
         return _run(base, caches, tokens, lengths, starts,
                     clients.astype(jnp.int32), slots, row_mask, ctx, adapter)
 
-    return compact_mixed if mixed else compact
+    return program_named(compact_mixed if mixed else compact,
+                         "compact_prefill")
 
 
 def make_page_copy(cfg: ModelConfig, scfg: ServeConfig):

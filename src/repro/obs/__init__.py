@@ -8,7 +8,8 @@ engines as ``obs=`` (ServingEngine / FinetuneEngine / SymbiosisEngine.from_spec)
   latency; the engines' ``stats`` dicts are mirrored in as gauges at
   snapshot time, keeping ``stats`` as the compatibility view).
 - ``obs.span(name)`` — reusable tick-phase spans emitting ``jax.profiler``
-  named scopes plus per-phase latency histograms.
+  named scopes plus per-phase latency histograms; ``obs.ticks`` keeps each
+  tick's seconds by phase (``obs.ticks.longest`` finds the stalls).
 - ``obs.events`` / ``obs.event(...)`` — the structured, drainable event log
   (client-visible via ``engine.drain_events(client=...)``).
 
@@ -34,11 +35,11 @@ from typing import Dict, List, Optional
 
 from repro.obs.events import UNSET, Event, EventLog
 from repro.obs.metrics import Counter, Gauge, Histogram, Metrics
-from repro.obs.trace import CaptureWindow, Span
+from repro.obs.trace import CaptureWindow, Span, TickLog
 
 __all__ = [
     "Obs", "Metrics", "Counter", "Gauge", "Histogram",
-    "Event", "EventLog", "Span", "CaptureWindow", "UNSET",
+    "Event", "EventLog", "Span", "TickLog", "CaptureWindow", "UNSET",
 ]
 
 
@@ -48,6 +49,7 @@ class Obs:
     def __init__(self, *, max_events: int = 10000) -> None:
         self.metrics = Metrics()
         self.events = EventLog(maxlen=max_events)
+        self.ticks = TickLog()
         self._spans: Dict[str, Span] = {}
         self._engines: Dict[str, object] = {}
         self._capture = CaptureWindow()
@@ -79,18 +81,21 @@ class Obs:
         sp = self._spans.get(name)
         if sp is None:
             sp = self._spans[name] = Span(
-                name, self.metrics.histogram("span_seconds", phase=name))
+                name, self.metrics.histogram("span_seconds", phase=name),
+                self.ticks)
         return sp
 
     def tick_start(self, engine: str) -> float:
         kind = self._capture.on_tick_start()
         if kind is not None:
             self.event(kind, engine=engine, log_dir=self._capture.log_dir or "")
+        self.ticks.start()
         return time.perf_counter()
 
     def tick_end(self, engine: str, tick: int, t0: float) -> None:
-        self.metrics.histogram("tick_seconds", engine=engine).observe(
-            time.perf_counter() - t0)
+        t1 = time.perf_counter()
+        self.metrics.histogram("tick_seconds", engine=engine).observe(t1 - t0)
+        self.ticks.end(engine, tick, t0, t1)
         kind = self._capture.on_tick_end()
         if kind is not None:
             self.event(kind, engine=engine, tick=tick)

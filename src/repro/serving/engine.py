@@ -206,7 +206,7 @@ def _pin_serving(fn, cfg, scfg, mesh, *, cache_arg=2):
         return (*out,
                 shardings.serving_cache_constrain(cfg, scfg, mesh, caches))
 
-    return pinned
+    return symbiosis.program_named(pinned, fn.__name__)
 
 
 # Jitted step builders are memoized on the (frozen, hashable) configs so
@@ -278,6 +278,7 @@ def _jit_page_copy(cfg, scfg, mesh=None):
             return shardings.serving_cache_constrain(
                 cfg, scfg, mesh, inner(caches, src, dst))
 
+        symbiosis.program_named(fn, inner.__name__)
     return jax.jit(fn, donate_argnums=0)
 
 
@@ -694,7 +695,9 @@ class ServingEngine:
                       "quarantined_clients": 0, "compact_prefill_batches": 0,
                       "compact_prefill_rows": 0, "compact_prefill_padded": 0,
                       "prefill_tokens_computed": 0, "prefix_hits": 0,
-                      "pages_shared": 0, "cow_copies": 0}
+                      "pages_shared": 0, "cow_copies": 0,
+                      "decode_d2h_bytes": 0, "prefill_d2h_bytes": 0,
+                      "page_ticks_held": 0, "page_ticks_reserved": 0}
         # telemetry (docs/observability.md): obs=None is a hard no-op — the
         # tick loop sees only `is not None` guards plus the shared null
         # span; attached, all instrumentation is host-side (perf_counter at
@@ -703,6 +706,9 @@ class ServingEngine:
         self._obs = obs
         self._span = _null_span if obs is None else obs.span
         self._last_tok_t: Dict[int, float] = {}
+        # per-client (decode token counter, inter-token histogram), looked
+        # up once per client rather than once per row and tick
+        self._decode_handles: Dict[int, tuple] = {}
         if obs is not None:
             obs.attach("serving", self)
 
@@ -834,6 +840,8 @@ class ServingEngine:
                 self._retire(req)
                 inflight.remove(req)
                 self._done.append(req)
+        if serve and self._paged:
+            self._count_pages()
 
         if (not inflight and attempted and not admitted_any and not serve
                 and not self._admission_faulted):
@@ -1212,7 +1220,9 @@ class ServingEngine:
                 jnp.asarray(toks), jnp.asarray(lengths), jnp.asarray(mask))
         self.stats["prefill_calls"] += 1
         self.stats["ragged_prefill_batches"] += 1
-        return np.asarray(logits)
+        logits = np.asarray(logits)
+        self.stats["prefill_d2h_bytes"] += logits.nbytes
+        return logits
 
     def _prefill_compact(self, newly: List[tuple]):
         """ONE compacted prefill for the whole tick's admissions (ISSUE 10
@@ -1259,7 +1269,7 @@ class ServingEngine:
             self.scfg, self.mesh, ext)
         key = (nb, S_pad, ext)
         if self._mixed:
-            with self._span("jit_dispatch"), self._mesh_ctx():
+            with self._span("prefill_dispatch"), self._mesh_ctx():
                 logits, finite, self.caches = tracecount.dispatch(
                     self, "compact_prefill", key, fn,
                     self.base, tuple(self.banks), self.caches,
@@ -1270,15 +1280,16 @@ class ServingEngine:
                     jnp.asarray(self._local_of[clients]),
                     jnp.asarray(rmask))
         else:
-            with self._span("jit_dispatch"), self._mesh_ctx():
+            with self._span("prefill_dispatch"), self._mesh_ctx():
                 logits, finite, self.caches = tracecount.dispatch(
                     self, "compact_prefill", key, fn,
                     self.base, self.banks[0], self.caches,
                     jnp.asarray(toks), jnp.asarray(lengths),
                     jnp.asarray(starts), jnp.asarray(clients),
                     jnp.asarray(slot_ids), jnp.asarray(rmask))
-        with self._span("device_sync"):
+        with self._span("prefill_sync"):
             logits = np.asarray(logits)
+        self.stats["prefill_d2h_bytes"] += logits.nbytes
         self.stats["prefill_calls"] += 1
         self.stats["compact_prefill_batches"] += 1
         self.stats["compact_prefill_rows"] += n
@@ -1433,7 +1444,9 @@ class ServingEngine:
                 jnp.asarray(toks), jnp.asarray(lengths), jnp.asarray(mask))
         self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += B * S
-        return np.asarray(logits)[slots]
+        logits = np.asarray(logits)
+        self.stats["prefill_d2h_bytes"] += logits.nbytes
+        return logits[slots]
 
     def _prefill_request_bankwide(self, req: Request, slots: List[int]) -> np.ndarray:
         """Seed-engine ablation: pad the request into a bank-wide [C, max_b,
@@ -1458,7 +1471,9 @@ class ServingEngine:
         self.caches = jax.tree.map(merge, self.caches, new_caches)
         self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += B * S
-        return np.asarray(logits)[c, slots]
+        logits = np.asarray(logits)
+        self.stats["prefill_d2h_bytes"] += logits.nbytes
+        return logits[c, slots]
 
     # ------------------------------------------------------------------
     # decode + sampling
@@ -1514,37 +1529,64 @@ class ServingEngine:
                     jnp.asarray(self._last_tok), jnp.asarray(active))
             with self._span("device_sync"):
                 lg = np.asarray(logits)
+            self.stats["decode_d2h_bytes"] += lg.nbytes
             lookup = lambda c, slots: lg[c, slots]
             finite_of = lambda c, slots: bool(np.isfinite(lg[c, slots]).all())
-        with self._span("scatter"):
-            obs = self._obs
+        obs = self._obs
+        with self._span("sample"):
             # ONE host timestamp after the decode's logits landed: every
             # stepping request's inter-token sample this tick shares it
             # (tick-boundary granularity, no per-request syncs)
             t_now = time.perf_counter() if obs is not None else 0.0
+            # every stepping row's next token first (None: non-finite
+            # logits, quarantined below), the bookkeeping after
+            sampled = []
             for req in stepping:
+                c, slots = req.client_id, self._slots_of[id(req)]
+                sampled.append((req, self._sample(lookup(c, slots), req)
+                                if finite_of(c, slots) else None))
+        with self._span("scatter"):
+            for req, nxt in sampled:
                 if self._left[id(req)] <= 0:
                     continue          # its client was quarantined mid-tick
-                c, slots = req.client_id, self._slots_of[id(req)]
-                if not finite_of(c, slots):
+                if nxt is None:
                     self._quarantine_request(req, "non-finite decode logits")
                     continue
-                nxt = self._sample(lookup(c, slots), req)
+                c, slots = req.client_id, self._slots_of[id(req)]
                 pos = req.max_new_tokens - self._left[id(req)]
                 req.generated[:, pos] = nxt
                 self._last_tok[c, slots] = nxt
                 self._left[id(req)] -= 1
                 self.stats["decode_tokens"] += len(slots)
                 if obs is not None:
-                    obs.metrics.counter("serve_decode_tokens_total",
-                                        client=c).inc(len(slots))
+                    tokens, gaps = self._decode_handle(c)
+                    tokens.inc(len(slots))
                     last = self._last_tok_t.get(id(req))
                     if last is not None:
-                        obs.metrics.histogram("serve_intertoken_seconds",
-                                              client=c).observe(t_now - last)
+                        gaps.observe(t_now - last)
                     self._last_tok_t[id(req)] = t_now
         self.stats["ticks"] += 1
         self.stats["batched_clients"] += len(serve)
+
+    def _decode_handle(self, c: int) -> tuple:
+        """Client ``c``'s decode-token counter and inter-token histogram."""
+        h = self._decode_handles.get(c)
+        if h is None:
+            m = self._obs.metrics
+            h = self._decode_handles[c] = (
+                m.counter("serve_decode_tokens_total", client=c),
+                m.histogram("serve_intertoken_seconds", client=c))
+        return h
+
+    def _count_pages(self):
+        """Page-ticks of a decode tick: pages held by requests (taken from
+        the pool) and pages promised to requests in flight but not yet
+        taken. Their ratio over a window is the share of what admission
+        claims that holds no token yet."""
+        free = sum(len(f) for f in self._free_pages)
+        self.stats["page_ticks_held"] += \
+            self.n_clients * self._pool_pages - free
+        self.stats["page_ticks_reserved"] += sum(self._reserved)
 
     def _decode_tick_compact(self, serve: set):
         """Compute-proportional tick: gather the serving clients' active
@@ -1588,6 +1630,7 @@ class ServingEngine:
         with self._span("device_sync"):
             lg = np.asarray(logits)
             fin = np.asarray(finite)
+        self.stats["decode_d2h_bytes"] += lg.nbytes + fin.nbytes
         row_of = {cs: i for i, cs in enumerate(rows)}
         self.stats["compact_rows"] += n
         self.stats["compact_padded"] += nb - n

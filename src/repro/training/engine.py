@@ -110,7 +110,7 @@ def _pin_train(fn, cfg, mesh):
         return (shardings.bank_state_constrain(cfg, mesh, new_bank),
                 shardings.bank_state_constrain(cfg, mesh, new_opt), metrics)
 
-    return pinned
+    return symbiosis.program_named(pinned, fn.__name__)
 
 
 # One compile cache per (model, adapter-config, step knobs) shared by every

@@ -126,6 +126,41 @@ def test_event_log_filtered_drain_and_cap():
     assert len(log.peek()) == 4 and log.dropped > 0
 
 
+def test_span_label_built_once_and_tick_log():
+    from repro.obs.trace import TickLog
+    obs = Obs()
+    sp = obs.span("prefill")
+    assert sp._label == "repro.obs/prefill" and obs.span("prefill") is sp
+    t0 = obs.tick_start("serving")
+    with obs.span("prefill"):
+        with obs.span("prefill_sync"):     # nested: listed, counted once
+            time.sleep(0.002)
+    with obs.span("sample"):
+        pass
+    time.sleep(0.003)                       # in no phase
+    obs.tick_end("serving", 1, t0)
+    (rec,) = obs.ticks.records
+    ph = rec["phases"]
+    assert set(ph) == {"prefill", "prefill_sync", "sample"}
+    assert ph["prefill"] >= ph["prefill_sync"] >= 0.002
+    assert rec["uncovered"] >= 0.003
+    assert rec["seconds"] == pytest.approx(
+        ph["prefill"] + ph["sample"] + rec["uncovered"], abs=1e-9)
+    assert rec["engine"] == "serving" and rec["start"] == t0
+    # the longest ticks of a window, of one engine, longest first
+    log = TickLog(maxlen=3)
+    for i, (eng, t, secs) in enumerate([("serving", 1.0, 0.5),
+                                        ("finetune", 2.0, 3.0),
+                                        ("serving", 3.0, 0.9),
+                                        ("serving", 4.0, 0.1)]):
+        log.start()
+        log.end(eng, i, t, t + secs)
+    assert len(log.records) == 3                    # the oldest dropped
+    assert [r["tick"] for r in log.longest(2)] == [1, 2]
+    assert [r["tick"] for r in log.longest(5, engine="serving")] == [2, 3]
+    assert [r["tick"] for r in log.longest(5, since=2.5, until=4.0)] == [2]
+
+
 # ---------------------------------------------------------------------------
 # contract 1: telemetry is bitwise-invisible (trace guard via conftest)
 # ---------------------------------------------------------------------------
