@@ -159,8 +159,9 @@ def decode_attn(q, k, v, pos, *, block_kv: int = 512, window: int = 0,
 
     ``block_tbl`` [B, n_blocks] switches to the PAGED layout: k/v are page
     pools [P, page_block, K, hd] shared across rows, and the kernel reads
-    each row's pages in place through its table row (scalar-prefetched into
-    the index_map — no dense view is gathered). ``k_scale``/``v_scale``
+    each row's live pages in place through its table row (scalar-prefetched;
+    the kernel copies them from the pool — no dense view is gathered, and
+    no entry past a row's last live page is read). ``k_scale``/``v_scale``
     [.., K, 1] switch to int8-quantized entries with per-head f32 scales.
     ``interpret=None`` auto-selects by backend (compiled on TPU, the
     byte-identical jnp stream twin elsewhere). ``via_gather=True`` is the

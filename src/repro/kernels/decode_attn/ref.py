@@ -15,12 +15,14 @@ def paged_view(pool, tbl):
     debugging.
 
     pool [P, block, ...]; tbl [B, n_blocks] int32 page ids.
-    Returns [B, n_blocks * block, ...]. Lanes reached through unallocated
-    table entries hold unrelated (but finite) data — callers must mask by
-    position validity, exactly as with an uninitialized dense cache."""
-    P, blk = pool.shape[:2]
+    Returns [B, n_blocks * block, ...]. An entry that names no page of the
+    pool (the engine's out-of-range sentinel) reads as zeros, as in the
+    kernels; lanes of other unallocated entries hold unrelated data —
+    callers must mask by position validity, exactly as with an
+    uninitialized dense cache."""
+    blk = pool.shape[1]
     B, n_blocks = tbl.shape
-    v = pool[jnp.clip(tbl, 0, P - 1)]
+    v = pool.at[tbl].get(mode="fill", fill_value=0)
     return v.reshape(B, n_blocks * blk, *pool.shape[2:])
 
 
@@ -59,7 +61,10 @@ def decode_attn_ref(q, k, v, pos, *, window: int = 0, block_tbl=None,
     if window:
         valid &= (pos[:, None] - t) < window
     s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
+    # a row with no valid key (pos < 0) attends to nothing: zeros, as the
+    # kernels give
+    p = jnp.where(valid.any(-1)[:, None, None, None],
+                  jax.nn.softmax(s, axis=-1), 0.0)
     if v_scale is not None:
         p = p * v_scale[..., 0].transpose(0, 2, 1)[:, :, None, :]
     out = jnp.einsum("bkgt,btkh->bkgh", p, v.astype(jnp.float32))

@@ -369,13 +369,20 @@ def _decode_attend_quant(params, cfg, q, cache_k, cache_ks, cache_v, cache_vs,
 # inactive slots (decode), so cross-slot corruption is impossible.
 #
 # READS go through the table-aware decode kernel (kernels/decode_attn): the
-# block table is scalar-prefetched and the kernel's index_map reads each
-# row's pages straight out of the pool — attention math is the kernel's
-# blocked online softmax, byte-identical between the bank-wide masked decode
-# and the engine's compacted decode (the kernel's custom_vmap rule folds a
-# vmapped client axis into extra pool pages, so both are literally the same
-# computation). ``_ORACLE`` reroutes the read through the gather-based test
-# oracle (same blocked math on a materialized dense view) — tests only.
+# block table is scalar-prefetched, the pools stay in HBM, and the kernel
+# walks each row's table in compute blocks of several pages (grid (rows,
+# blocks)), copying a live block's pages straight out of the pool while the
+# previous block computes. The dead-page rule: only pages that hold a
+# position in the row's window [pos - window + 1, pos] and name a page of
+# the pool are read — an entry past the row's last live page is never
+# dereferenced, and a row masked out of the step (``active`` False) attends
+# at position -1, so it reads no page at all. Attention math is the
+# kernel's blocked online softmax, byte-identical between the bank-wide
+# masked decode and the engine's compacted decode (the kernel's custom_vmap
+# rule folds a vmapped client axis into extra pool pages, so both are
+# literally the same computation). ``_ORACLE`` reroutes the read through the
+# gather-based test oracle (same blocked math on a materialized dense view)
+# — tests only.
 
 _ORACLE = False
 
@@ -397,11 +404,15 @@ def paged_gather_oracle():
 
 
 def _paged_attend(params, cfg, q, pools, tbl, pos, lin: LinearFns,
-                  path_prefix: str):
+                  path_prefix: str, active=None):
     """Attention of one query token read in place from paged pools.
 
     q [B,1,H,hd]; pools = (k, v) or (k, k_s, v, v_s) page pools; tbl
-    [B, n_blocks]; pos [B]. Returns [B,1,d_model] after the o-projection."""
+    [B, n_blocks]; pos [B]; active [B] bool (None = all; an inactive row
+    reads no page and attends to nothing). Returns [B,1,d_model] after the
+    o-projection."""
+    if active is not None:
+        pos = jnp.where(active, pos, -1)
     B = q.shape[0]
     hd, K, H = cfg.hd, cfg.n_kv_heads, cfg.hp
     G = H // K
@@ -564,7 +575,7 @@ def mha_decode_paged(params, cfg, x, pool_k, pool_v, tbl, pos, lin: LinearFns,
     pool_k = paged_token_write(pool_k, tbl, pos, k[:, 0], active)
     pool_v = paged_token_write(pool_v, tbl, pos, v[:, 0], active)
     out = _paged_attend(params, cfg, q, (pool_k, pool_v), tbl, pos, lin,
-                        path_prefix)
+                        path_prefix, active)
     return out, pool_k, pool_v
 
 
@@ -583,7 +594,7 @@ def mha_decode_quant_paged(params, cfg, x, pool_k, pool_ks, pool_v, pool_vs,
     pool_v = paged_token_write(pool_v, tbl, pos, vq[:, 0], active)
     pool_vs = paged_token_write(pool_vs, tbl, pos, vs[:, 0], active)
     out = _paged_attend(params, cfg, q, (pool_k, pool_ks, pool_v, pool_vs),
-                        tbl, pos, lin, path_prefix)
+                        tbl, pos, lin, path_prefix, active)
     return out, pool_k, pool_ks, pool_v, pool_vs
 
 

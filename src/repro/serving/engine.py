@@ -172,6 +172,7 @@ from repro.core.scheduler import ClientSpec, TickPolicy, simulate
 from repro.faults.health import (AdmissionStall, HealthPolicy, HealthRecord,
                                  HealthState, classify)
 from repro.faults.plan import TransientFault
+from repro.kernels.decode_attn.decode_attn import pages_read
 from repro.serving.prefix_cache import PrefixIndex, sharable_tokens
 
 # disabled-telemetry span: one shared, reusable null context manager — the
@@ -697,7 +698,8 @@ class ServingEngine:
                       "prefill_tokens_computed": 0, "prefix_hits": 0,
                       "pages_shared": 0, "cow_copies": 0,
                       "decode_d2h_bytes": 0, "prefill_d2h_bytes": 0,
-                      "page_ticks_held": 0, "page_ticks_reserved": 0}
+                      "page_ticks_held": 0, "page_ticks_reserved": 0,
+                      "attn_pages_read": 0, "attn_pages_table": 0}
         # telemetry (docs/observability.md): obs=None is a hard no-op — the
         # tick loop sees only `is not None` guards plus the shared null
         # span; attached, all instrumentation is host-side (perf_counter at
@@ -1634,6 +1636,13 @@ class ServingEngine:
         row_of = {cs: i for i, cs in enumerate(rows)}
         self.stats["compact_rows"] += n
         self.stats["compact_padded"] += nb - n
+        # the pages of the compute blocks paged attention visits for each
+        # row (this tick's token sits at wpos - 1; a padding row reads
+        # none), against the bucket's whole tables
+        self.stats["attn_pages_read"] += pages_read(
+            [self._wpos[c, s] - 1 for c, s in rows], self._n_blocks,
+            self._blk, self.cfg.sliding_window)
+        self.stats["attn_pages_table"] += nb * self._n_blocks
         return (lambda c, ss: lg[[row_of[(c, s)] for s in ss]],
                 lambda c, ss: bool(fin[[row_of[(c, s)] for s in ss]].all()))
 

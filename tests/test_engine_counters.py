@@ -32,9 +32,9 @@ METHODS = [
 ]
 
 
-def _engine(obs=None, pool_pages=10):
+def _engine(obs=None, pool_pages=10, max_seq=48):
     cfg = tiny()
-    scfg = ServeConfig(n_clients=6, max_seq=48, page_block=8,
+    scfg = ServeConfig(n_clients=6, max_seq=max_seq, page_block=8,
                        pool_pages=pool_pages)
     base = get_model(cfg).init_params(jax.random.PRNGKey(0))
     banks = [ad_lib.init_client_bank(cfg, a, 2, jax.random.PRNGKey(5 + i))
@@ -43,7 +43,7 @@ def _engine(obs=None, pool_pages=10):
                          max_batch_per_client=2, obs=obs)
 
 
-def _requests(vocab, n=10, seed=0):
+def _requests(vocab, n=10, seed=0, prompt=(5, 20), new=(2, 12)):
     rng = np.random.default_rng(seed)
     out = []
     for i in range(n):
@@ -51,8 +51,8 @@ def _requests(vocab, n=10, seed=0):
             else None
         out.append(Request(client_id=i % 6,
                            prompt=rng.integers(0, vocab, (1, int(
-                               rng.integers(5, 20)))).astype(np.int32),
-                           max_new_tokens=int(rng.integers(2, 12)),
+                               rng.integers(*prompt)))).astype(np.int32),
+                           max_new_tokens=int(rng.integers(*new)),
                            sampling=sp, arrive_tick=i // 2))
     return out
 
@@ -99,6 +99,41 @@ def test_d2h_bytes_and_page_ticks_per_tick():
     assert decoded == eng.stats["ticks"] > 0
     assert eng.stats["page_ticks_reserved"] > 0
     assert eng.stats["prefill_d2h_bytes"] > 0
+
+
+def test_attn_pages_counted_per_compact_tick():
+    """Each compact decode tick adds, for each real row, the pages of the
+    128-token compute blocks (16 pages of 8 tokens) up to the position of
+    the row's token, at most the table's 40; a padding row adds none. The
+    table count is the bucket's rows times 40. The positions are the ones
+    the device holds for the rows when the step runs."""
+    eng = _engine(pool_pages=48, max_seq=320)
+    seen = []
+    inner = eng._decode_tick_compact
+
+    def spy(serve):
+        rows = [(c, s) for c in sorted(serve) for s in eng._active_slots[c]]
+        pos = [int(eng._wpos[c, s]) - 1 for c, s in rows]
+        assert pos == [int(eng.caches["pos"][c, s]) for c, s in rows]
+        seen.append(pos)
+        return inner(serve)
+
+    eng._decode_tick_compact = spy
+    ticks = _drive(eng, _requests(eng.cfg.vocab, n=10, prompt=(60, 280),
+                                  new=(10, 40)))
+    decoded = [(b, a) for b, a, _ in ticks if a["ticks"] > b["ticks"]]
+    assert len(decoded) == len(seen) > 0
+    for (before, after), pos in zip(decoded, seen):
+        d = {k: after[k] - before[k] for k in after}
+        assert d["attn_pages_read"] == sum(min(40, 16 * (p // 128 + 1))
+                                           for p in pos)
+        assert d["attn_pages_table"] == 40 * (d["compact_rows"]
+                                              + d["compact_padded"])
+    # rows in the first block, in the second, and in the third, which
+    # holds only the table's last 8 columns
+    flat = [p for pos in seen for p in pos]
+    assert min(flat) < 128 and any(128 <= p < 256 for p in flat)
+    assert max(flat) >= 256
 
 
 def test_decode_spans_are_decode_only():

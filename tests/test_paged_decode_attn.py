@@ -31,7 +31,7 @@ from repro.kernels.sgmv.ops import sgmv
 from repro.kernels.sgmv.sgmv import sgmv_pallas, sgmv_stream
 
 
-def _paged_case(B, K, G, hd, P, blk, nb, seed=0, quant=False):
+def _paged_case(B, K, G, hd, P, blk, nb, seed=0, quant=False, pos=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = jax.random.normal(ks[0], (B, K, G, hd))
     if quant:
@@ -45,23 +45,36 @@ def _paged_case(B, K, G, hd, P, blk, nb, seed=0, quant=False):
         kss = vss = None
     # scattered page assignment: rows' pages are arbitrary pool entries
     tbl = jax.random.permutation(ks[5], P)[:B * nb].reshape(B, nb).astype(jnp.int32)
-    pos = jax.random.randint(jax.random.PRNGKey(seed + 7), (B,), 0, nb * blk)
-    return q, pk, pv, kss, vss, tbl, pos
+    if pos is None:
+        pos = jax.random.randint(jax.random.PRNGKey(seed + 7), (B,), 0,
+                                 nb * blk)
+    return q, pk, pv, kss, vss, tbl, jnp.asarray(pos, jnp.int32)
 
 
-# (B, K, G, hd, P, blk, nb, window): standard / non-dividing page count /
-# single-page rows / sliding window
+# (B, K, G, hd, P, blk, nb, window[, pos]): standard / non-dividing page
+# count / single-page rows / sliding window; then, at fixed positions, rows
+# whose context ends mid-block, exactly at a compute block's end, at the
+# next block's first key, at pos 0, and a row with no live block (pos -1);
+# and a table of 12 columns that its 8-page blocks do not divide, under a
+# window whose first live block is not block 0
 CASES = [(3, 2, 2, 32, 16, 8, 4, 0),
          (2, 1, 4, 64, 11, 16, 3, 0),
          (1, 2, 2, 32, 4, 8, 1, 0),
-         (3, 2, 2, 32, 16, 8, 4, 12)]
+         (3, 2, 2, 32, 16, 8, 4, 12),
+         (5, 2, 2, 32, 160, 8, 32, 0, (70, 127, 128, 0, -1)),
+         (3, 2, 2, 32, 40, 16, 12, 40, (185, 130, 100))]
+
+
+def _case(case, **kw):
+    B, K, G, hd, P, blk, nb, w, *pos = case
+    return w, _paged_case(B, K, G, hd, P, blk, nb,
+                          pos=pos[0] if pos else None, **kw)
 
 
 class TestPagedKernelContracts:
     @pytest.mark.parametrize("case", CASES)
     def test_pallas_interpret_equals_stream(self, case):
-        B, K, G, hd, P, blk, nb, w = case
-        q, pk, pv, _, _, tbl, pos = _paged_case(B, K, G, hd, P, blk, nb)
+        w, (q, pk, pv, _, _, tbl, pos) = _case(case)
         a = jax.jit(functools.partial(paged_decode_attn_pallas, window=w,
                                       interpret=True))(q, pk, pv, tbl, pos)
         b = jax.jit(functools.partial(paged_decode_attn_stream, window=w))(
@@ -70,9 +83,7 @@ class TestPagedKernelContracts:
 
     @pytest.mark.parametrize("case", CASES)
     def test_quant_pallas_interpret_equals_stream(self, case):
-        B, K, G, hd, P, blk, nb, w = case
-        q, pk, pv, kss, vss, tbl, pos = _paged_case(B, K, G, hd, P, blk, nb,
-                                                    quant=True)
+        w, (q, pk, pv, kss, vss, tbl, pos) = _case(case, quant=True)
         a = jax.jit(functools.partial(paged_decode_attn_quant_pallas, window=w,
                                       interpret=True))(q, pk, kss, pv, vss, tbl, pos)
         b = jax.jit(functools.partial(paged_decode_attn_quant_stream, window=w))(
@@ -84,9 +95,7 @@ class TestPagedKernelContracts:
     def test_table_read_equals_gather_reference(self, case, quant):
         """In-place page reads == gather-then-same-math (the oracle that
         replaced the PR-2 in-step gather)."""
-        B, K, G, hd, P, blk, nb, w = case
-        q, pk, pv, kss, vss, tbl, pos = _paged_case(B, K, G, hd, P, blk, nb,
-                                                    quant=quant)
+        w, (q, pk, pv, kss, vss, tbl, pos) = _case(case, quant=quant)
         kw = {"k_scale": kss, "v_scale": vss} if quant else {}
         direct = decode_attn(q, pk, pv, pos, block_tbl=tbl, window=w, **kw)
         oracle = decode_attn(q, pk, pv, pos, block_tbl=tbl, window=w,
@@ -96,13 +105,48 @@ class TestPagedKernelContracts:
     @pytest.mark.parametrize("case", CASES)
     @pytest.mark.parametrize("quant", [False, True])
     def test_matches_full_softmax_oracle(self, case, quant):
-        B, K, G, hd, P, blk, nb, w = case
-        q, pk, pv, kss, vss, tbl, pos = _paged_case(B, K, G, hd, P, blk, nb,
-                                                    quant=quant)
+        w, (q, pk, pv, kss, vss, tbl, pos) = _case(case, quant=quant)
         kw = {"k_scale": kss, "v_scale": vss} if quant else {}
         y = decode_attn(q, pk, pv, pos, block_tbl=tbl, window=w, **kw)
         yr = decode_attn_ref(q, pk, pv, pos, window=w, block_tbl=tbl, **kw)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                                   rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("window", [0, 24])
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_dead_pages_are_never_read(self, quant, window):
+        """Entries past each row's last live page hold the engine's
+        out-of-range sentinel, and every pool page no entry names is NaN.
+        The kernel (whose interpreter raises on an out-of-bounds read), its
+        twin and the gather reference agree bit for bit, and are finite."""
+        B, K, G, hd, P, blk, nb = 4, 2, 2, 32, 96, 8, 20
+        q, pk, pv, kss, vss, tbl, pos = _paged_case(
+            B, K, G, hd, P, blk, nb, quant=quant, pos=(0, 37, 127, 150))
+        live = np.arange(nb)[None] <= np.asarray(pos)[:, None] // blk
+        tbl = jnp.where(live, tbl, 1 << 30)
+        dead = ~np.isin(np.arange(P), np.asarray(tbl))
+        poison = lambda x: x.at[dead].set(jnp.nan)
+        if quant:
+            kss, vss = poison(kss), poison(vss)
+            args = (q, pk, kss, pv, vss, tbl, pos)
+            kernel = paged_decode_attn_quant_pallas
+            twin = paged_decode_attn_quant_stream
+            kw = {"k_scale": kss, "v_scale": vss}
+        else:
+            pk, pv = poison(pk), poison(pv)
+            args = (q, pk, pv, tbl, pos)
+            kernel, twin, kw = paged_decode_attn_pallas, paged_decode_attn_stream, {}
+        a = jax.jit(functools.partial(kernel, window=window,
+                                      interpret=True))(*args)
+        b = jax.jit(functools.partial(twin, window=window))(*args)
+        oracle = decode_attn(q, pk, pv, pos, block_tbl=tbl, window=window,
+                             via_gather=True, **kw)
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(oracle))
+        ref = decode_attn_ref(q, pk, pv, pos, window=window, block_tbl=tbl,
+                              **kw)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
     def test_vmapped_clients_equal_flat_pool_concat(self):

@@ -86,7 +86,7 @@ def _kernel_calls(hlo: str, name: str) -> int:
                if "tpu_custom_call" in line and f"/{name}/" in line)
 
 
-@pytest.mark.parametrize("rows", [4, 64])
+@pytest.mark.parametrize("rows", [4, 32, 64])
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_decode_attn_compiles(one_chip, rows, quant):
     hlo = _compile(_paged_attn_fn(quant), one_chip,
