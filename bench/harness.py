@@ -9,7 +9,11 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 - ``bench/traffic/<mix>.json``      the mix's parameters; its ``kind``
   names the driver module ``bench/traffic/<kind>.py``;
 - ``bench/metrics/<metric>.py``     one reader per per-layer metric, with
-  ``read(view) -> float | None``.
+  ``read(view) -> float | None``;
+- ``bench/arch/<arch>.py``          the model family that the configuration's
+  ``model["arch"]`` names: its base leaves, adapter target widths, the
+  reference's layer, the FLOPs a token requires and the published widths
+  it checks (``bench/arch/dense.py`` lists what a family module gives).
 """
 from __future__ import annotations
 
@@ -73,6 +77,16 @@ class Registry:
 
     def reader(self, metric: str):
         return self.module("metrics", metric + ".py")
+
+    def arch(self, name: str):
+        return self.module("arch", name + ".py")
+
+
+def family(cfg: dict, given=None):
+    """The family module of a model configuration: ``given`` where the
+    caller holds it (a run's, or a reader's ``view.family``), else the
+    benchmark's own ``bench/arch/<arch>.py`` for its ``arch``."""
+    return given or Registry().arch(cfg["arch"])
 
 
 def device_check(chips: int, require_tpu: bool = True):
@@ -215,6 +229,7 @@ class Run:
         self.seed, self.seconds, self.traced = args.seed, args.seconds, \
             bool(args.trace)
         self.config, self.traffic = cell["config"], cell["traffic"]
+        self.family = registry.arch(self.config["model"]["arch"])
         self.metrics = {}            # end-to-end values by name
         self.view = None             # what per-layer readers read
         self.correct = False

@@ -7,17 +7,11 @@ the LoRA rows alone: each real LoRA token reads x (d_in) and writes y
 (d_out) in bf16, and each distinct adapter in the call reads A [d_in, r]
 and B [r, d_out] once, in bf16; FLOPs are ``2 * r * (d_in + d_out)`` per
 token. Rows of other methods, padding rows and block padding are not
-counted, so they show as a lower share."""
-from bench import trace, window
+counted, so they show as a lower share. A target's widths are the model
+family's (``target_dims`` in ``bench/arch/<arch>.py``)."""
+from bench import harness, trace, window
 
 KERNEL = "sgmv"
-
-
-def _dims(cfg):
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    H, K, ff = cfg["n_heads"], cfg["n_kv_heads"], cfg["d_ff"]
-    return {"q": (d, H * hd), "k": (d, K * hd), "v": (d, K * hd),
-            "o": (H * hd, d), "gate": (d, ff), "up": (d, ff), "down": (ff, d)}
 
 
 def read(view):
@@ -37,7 +31,8 @@ def read(view):
         if bank[c]["method"] == "lora":
             d = calls.setdefault((tick, "prefill"), {})
             d[c] = d.get(c, 0) + S
-    dims = _dims(cfg)
+    fam = harness.family(cfg, getattr(view, "family", None))
+    dims = fam.target_dims(cfg)
     least = 0.0
     for toks in calls.values():
         for b in {bank[c]["name"]: bank[c] for c in toks}.values():

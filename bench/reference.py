@@ -1,22 +1,16 @@
-"""The plain reference: a dense GQA decoder with qk-norm, RoPE and SwiGLU,
-with LoRA, IA3 and prefix adapters, in float32 under
-``jax.default_matmul_precision("highest")``.
+"""The plain reference: the configuration's model family (its
+``layer``, from ``bench/arch/<arch>.py``) with LoRA, IA3 and prefix
+adapters, the final norm and head, in float32 under
+``jax.default_matmul_precision("highest")``; and what every family shares:
+the norm, RoPE, the matmul with its lower-precision control, the serving
+scorer and the fine-tuning loss, gradients and AdamW.
 
 It follows the configuration as the program runs it, written from the
-published equations and from the adapter semantics below, and imports
-nothing of the program. Weights come from the seed through
-``bench.weights`` (the same generator that made the program's, run again),
-in their served dtype; each layer is cast to float32 as it runs, so a
-36-layer model never sits in float32 on the chip.
-
-Adapter semantics (per layer, per client):
-
-- LoRA on a target t: ``y_t = x W_t + (alpha / r) * (x A_t) B_t``;
-- IA3: the outputs of k and v are scaled elementwise (before qk-norm), and
-  the input of ``down`` (the SwiGLU product) is scaled elementwise;
-- prefix: a second softmax branch per layer over ``n_prefix`` virtual keys
-  and values, ``0.1 * softmax((h W_q) P_k^T / sqrt(hd)) P_v W_o``, with
-  the un-normalised, un-rotated query, added to the attention output.
+published equations and from the adapter semantics the family module
+states, and imports nothing of the program. Weights come from the seed
+through ``bench.weights`` (the same generator that made the program's, run
+again), in their served dtype; each layer is cast to float32 as it runs,
+so a 36-layer model never sits in float32 on the chip.
 
 ``lowp="fp8"`` rounds every matmul's inputs to float8 (e4m3, one scale per
 tensor) before a float32 product: the control that a lower precision than
@@ -32,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import harness
 from bench import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -94,51 +89,6 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def layer(cfg: dict, w: dict, ad: dict, method: dict, x, mm):
-    """One decoder layer on one sequence. x [T, d] float32; ``w`` one
-    layer's base leaves, ``ad`` one adapter's leaves at this layer (or
-    empty), ``method`` its description (or None)."""
-    T = x.shape[0]
-    H, K, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    m = method["method"] if method else ""
-    pos = jnp.arange(T)
-
-    def proj(h, t, wt):
-        y = mm("ti,io->to", h, wt)
-        if m == "lora" and t in ad:
-            scale = method["alpha"] / method["rank"]
-            y = y + scale * mm("tr,ro->to", mm("ti,ir->tr", h, ad[t]["A"]),
-                               ad[t]["B"])
-        if m == "ia3" and t in ad and t != "down":
-            y = y * ad[t]["scale"]
-        return y
-
-    h = rms(x, w["ln1"])
-    q = proj(h, "q", w["wq"]).reshape(T, H, hd)
-    k = proj(h, "k", w["wk"]).reshape(T, K, hd)
-    v = proj(h, "v", w["wv"]).reshape(T, K, hd)
-    if cfg["qk_norm"]:
-        q, k = rms(q, w["q_norm"]), rms(k, w["k_norm"])
-    q, k = rope(q, pos, cfg["rope_theta"]), rope(k, pos, cfg["rope_theta"])
-    G = H // K
-    k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
-    s = mm("thd,shd->hts", q, k) / math.sqrt(hd)
-    s = jnp.where(pos[:, None] >= pos[None, :], s, -jnp.inf)
-    att = mm("hts,shd->thd", jax.nn.softmax(s, axis=-1), v)
-    out = proj(att.reshape(T, H * hd), "o", w["wo"])
-    if m == "prefix":
-        qp = mm("ti,io->to", h, w["wq"]).reshape(T, K, G, hd)
-        sp = mm("tkgh,pkh->tkgp", qp, ad["prefix_k"]) / math.sqrt(hd)
-        op = mm("tkgp,pkh->tkgh", jax.nn.softmax(sp, axis=-1), ad["prefix_v"])
-        out = out + 0.1 * mm("ti,io->to", op.reshape(T, H * hd), w["wo"])
-    x = x + out
-    h = rms(x, w["ln2"])
-    a = jax.nn.silu(proj(h, "gate", w["gate"])) * proj(h, "up", w["up"])
-    if m == "ia3" and "down" in ad:
-        a = a * ad["down"]["scale"]
-    return x + proj(a, "down", w["down"])
-
-
 @contextlib.contextmanager
 def _highest():
     with jax.default_matmul_precision("highest"):
@@ -157,26 +107,29 @@ class ServeReference:
     every layer: the layer's weights are drawn from the seed inside it."""
 
     def __init__(self, cfg: dict, seed: int, banks: list, *, lowp: str = "",
-                 bucket_min: int = 256, base=None):
+                 bucket_min: int = 256, base=None, family=None):
         self.cfg, self.seed, self.banks = cfg, seed, banks
+        self.family = harness.family(cfg, family)
         self.key = jax.random.key_data(W.root_key(seed))
         self.mm = make_mm(lowp)
         self.bucket_min = bucket_min
         self._layer = {}
-        self.base = W.make_base(cfg, seed) if base is None else base
+        if base is None:
+            base = W.make_base(cfg, seed, self.family)
+        self.base = base
         self._head = jax.jit(self._head_fn)
 
     def _layer_fn(self, bank):
         if bank not in self._layer:
-            cfg, mm = self.cfg, self.mm
+            cfg, mm, fam = self.cfg, self.mm, self.family
             b = next(b for b in self.banks if b["name"] == bank)
 
             def fn(layers, key, li, index, x):
                 k = jax.random.wrap_key_data(key)
-                w = layer_weights(layers, li)
-                ad = _adapter_layer_k(cfg, b, k, index, li)
+                w = fam.layer_weights(layers, li)
+                ad = _adapter_layer_k(cfg, fam, b, k, index, li)
                 with _highest():
-                    return layer(cfg, w, ad, b, x, mm)
+                    return fam.layer(cfg, w, ad, b, x, mm)
 
             self._layer[bank] = jax.jit(fn)
         return self._layer[bank]
@@ -212,18 +165,6 @@ def served_gaps(ref_logits, served):
     return np.asarray(best - got)
 
 
-def layer_weights(layers, li) -> dict:
-    """Layer ``li`` of the stacked base leaves, in float32, by short name
-    (``ln1``, ``wq``, ``gate``, ...)."""
-    out = {}
-    for group, leaves in layers.items():
-        for name, x in leaves.items():
-            key = group if name == "scale" else name
-            out[key] = jax.lax.dynamic_index_in_dim(
-                x, li, 0, keepdims=False).astype(jnp.float32)
-    return out
-
-
 def head(base, x, mm, lead: str):
     """Final norm and output head (tied to the embedding or not)."""
     h = rms(x, base["final_norm"]["scale"].astype(jnp.float32))
@@ -233,15 +174,9 @@ def head(base, x, mm, lead: str):
     return mm(f"{lead}d,dv->{lead}v", h, w)
 
 
-def _flat(w) -> dict:
-    """One layer's grouped leaves by short name, as ``layer`` takes them."""
-    return {(g if n == "scale" else n): x for g, leaves in w.items()
-            for n, x in leaves.items()}
-
-
-def _adapter_layer_k(cfg, method, key, index, li):
+def _adapter_layer_k(cfg, family, method, key, index, li):
     out = {}
-    for path, kind, shape, fan in W.adapter_leaves(cfg, method):
+    for path, kind, shape, fan in W.adapter_leaves(cfg, method, family):
         name = f"adapter/{method['name']}/" + "/".join(path)
         k = jax.random.fold_in(jax.random.fold_in(
             jax.random.fold_in(key, W._name_id(name)), index), li)
@@ -267,24 +202,26 @@ class TrainReference:
     rematerialised scan (each layer's weights drawn from the seed inside the
     scan body), plus the AdamW update the job's optimizer is set to."""
 
-    def __init__(self, cfg: dict, seed: int, *, lowp: str = ""):
+    def __init__(self, cfg: dict, seed: int, *, lowp: str = "",
+                 family=None):
         self.cfg, self.seed = cfg, seed
+        self.family = harness.family(cfg, family)
         self.key = jax.random.key_data(W.root_key(seed))
         self.mm = make_mm(lowp)
-        self.base = W.make_base(cfg, seed)
+        self.base = W.make_base(cfg, seed, self.family)
         self._vg = {}
 
     def _value_and_grad(self, method):
         name = method["name"]
         if name not in self._vg:
-            cfg, mm = self.cfg, self.mm
+            cfg, mm, fam = self.cfg, self.mm, self.family
 
             def loss_fn(ad_stack, base, tokens, labels):
                 def body(x, inp):
                     w, ad = inp
-                    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-                    return jax.vmap(lambda xx: layer(cfg, _flat(w), ad,
-                                                     method, xx, mm))(x), None
+                    w = fam.layer_weights(w)
+                    return jax.vmap(lambda xx: fam.layer(cfg, w, ad, method,
+                                                         xx, mm))(x), None
 
                 with _highest():
                     x = base["embed"][tokens].astype(jnp.float32)
@@ -302,8 +239,8 @@ class TrainReference:
     def adapter_init(self, method, bank: str, index: int):
         """The job's starting adapter, stacked over layers, from the seed."""
         k = W.root_key(self.seed)
-        layers = [_adapter_layer_k(self.cfg, dict(method, name=bank), k,
-                                   index, li)
+        layers = [_adapter_layer_k(self.cfg, self.family,
+                                   dict(method, name=bank), k, index, li)
                   for li in range(self.cfg["n_layers"])]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
 

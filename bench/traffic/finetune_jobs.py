@@ -83,10 +83,11 @@ def build(run, obs):
     from bench import weights
     md, mix = run.config["model"], run.traffic
     t = time.perf_counter()
-    base = weights.make_base(md, run.seed)
+    base = weights.make_base(md, run.seed, run.family)
     adapters = []
     for i, j in enumerate(mix["jobs"]):
-        bank = weights.make_bank(md, method_of(j), run.seed, j["name"], 1)
+        bank = weights.make_bank(md, method_of(j), run.seed, j["name"], 1,
+                                 family=run.family)
         adapters.append(jax.tree.map(lambda x: x[0], bank))
     jax.block_until_ready((base, adapters))
     t_weights = time.perf_counter() - t
@@ -214,9 +215,9 @@ def run(run):
                                    "median": float(np.median(took)),
                                    "longest": took[-1]}})
     from types import SimpleNamespace
-    run.view = SimpleNamespace(cfg=run.config["model"], mix=mix,
-                               ticks=ticks, snaps=snaps, trace=run.trace,
-                               peaks=harness.peaks(run))
+    run.view = SimpleNamespace(cfg=run.config["model"], family=run.family,
+                               mix=mix, ticks=ticks, snaps=snaps,
+                               trace=run.trace, peaks=harness.peaks(run))
     run.attempted = len(jobs)
     run.failed = sum(1 for j in jobs if j.status != "active")
 
@@ -267,7 +268,7 @@ def follow(run, *, lowp: str = "", rows=None, data_of=None) -> list:
     from bench.reference import TrainReference
     mix, md = run.traffic, run.config["model"]
     steps = mix["check"]["steps"]
-    ref = TrainReference(md, run.seed, lowp=lowp)
+    ref = TrainReference(md, run.seed, lowp=lowp, family=run.family)
     out = []
     for i, j in enumerate(mix["jobs"]):
         meth = method_of(j)

@@ -150,9 +150,9 @@ def build(run, obs):
     md, mix = run.config["model"], run.traffic
     cfg = ModelConfig(**md)
     t = time.perf_counter()
-    base = weights.make_base(md, run.seed)
-    banks = [weights.make_bank(md, b, run.seed, b["name"], b["clients"])
-             for b in mix["banks"]]
+    base = weights.make_base(md, run.seed, run.family)
+    banks = [weights.make_bank(md, b, run.seed, b["name"], b["clients"],
+                               family=run.family) for b in mix["banks"]]
     import jax
     jax.block_until_ready((base, banks))
     t_weights = time.perf_counter() - t
@@ -492,7 +492,8 @@ def _view(run, w, shapes):
 
     from bench import harness
     return SimpleNamespace(
-        cfg=run.config["model"], mix=run.traffic, recs=w["recs"],
+        cfg=run.config["model"], family=run.family, mix=run.traffic,
+        recs=w["recs"],
         measured=w["measured"], ticks=w["ticks"], snaps=w["snaps"],
         trace=run.trace, peaks=harness.peaks(run), shapes=shapes)
 
@@ -541,9 +542,9 @@ def readings(run, recs, kept, lowp: str = "") -> dict:
     from bench.reference import ServeReference, served_gaps
     mix, md = run.traffic, run.config["model"]
     where = {c: (b, i) for c, b, i in clients_of(mix)}
-    ref = ServeReference(md, run.seed, mix["banks"])
+    ref = ServeReference(md, run.seed, mix["banks"], family=run.family)
     low = ServeReference(md, run.seed, mix["banks"], lowp=lowp,
-                         base=ref.base) if lowp else None
+                         base=ref.base, family=run.family) if lowp else None
     out = {"gap": 0.0, "error": 0.0, "fault.gap": 0.0, "requests": 0,
            "tokens": 0}
     if low is not None:

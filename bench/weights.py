@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import harness
+
 
 def root_key(seed: int):
     """A threefry key from any seed that fits in 64 bits."""
@@ -90,35 +92,8 @@ def _vmap_first(fn, n):
 
 
 # ---------------------------------------------------------------------------
-# The dense decoder's base parameters
+# The base parameters
 # ---------------------------------------------------------------------------
-
-def base_leaves(cfg: dict):
-    """(path, kind, per-layer shape or None, fan_in) of every base leaf of a
-    dense GQA decoder as the program lays it out: stacked layers under
-    ``layers``, tied or separate head."""
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    H, K, ff, V = cfg["n_heads"], cfg["n_kv_heads"], cfg["d_ff"], cfg["vocab"]
-    out = [(("embed",), "embed", None, (V, d), 1),
-           (("final_norm", "scale"), "norm", None, (d,), 1)]
-    if not cfg["tie_embeddings"]:
-        out.append((("lm_head",), "linear", None, (d, V), d))
-    lay = [(("ln1", "scale"), "norm", (d,), 1),
-           (("ln2", "scale"), "norm", (d,), 1),
-           (("attn", "wq"), "linear", (d, H * hd), d),
-           (("attn", "wk"), "linear", (d, K * hd), d),
-           (("attn", "wv"), "linear", (d, K * hd), d),
-           (("attn", "wo"), "linear", (H * hd, d), H * hd),
-           (("mlp", "gate"), "linear", (d, ff), d),
-           (("mlp", "up"), "linear", (d, ff), d),
-           (("mlp", "down"), "linear", (ff, d), ff)]
-    if cfg["qk_norm"]:
-        lay += [(("attn", "q_norm"), "norm", (hd,), 1),
-                (("attn", "k_norm"), "norm", (hd,), 1)]
-    for path, kind, shape, fan in lay:
-        out.append((("layers",) + path, kind, "layer", shape, fan))
-    return out
-
 
 def _set(tree, path, value):
     node = tree
@@ -127,15 +102,17 @@ def _set(tree, path, value):
     node[path[-1]] = value
 
 
-def make_base(cfg: dict, seed: int):
-    """The whole base tree, in the served dtype, from one jitted call (the
-    key is an argument, so every seed runs the one compiled program)."""
+def make_base(cfg: dict, seed: int, family=None):
+    """The whole base tree of the configuration's family (its
+    ``base_leaves``), in the served dtype, from one jitted call (the key is
+    an argument, so every seed runs the one compiled program)."""
     dtype = jnp.dtype(cfg["param_dtype"])
+    leaves = harness.family(cfg, family).base_leaves(cfg)
 
     def build(key_data):
         key = jax.random.wrap_key_data(key_data)
         tree = {}
-        for path, kind, per, shape, fan in base_leaves(cfg):
+        for path, kind, per, shape, fan in leaves:
             name = "/".join(path)
             if per is None:
                 v = draw(key, name, kind, shape, dtype, fan)
@@ -152,17 +129,11 @@ def make_base(cfg: dict, seed: int):
 # Adapters
 # ---------------------------------------------------------------------------
 
-def target_dims(cfg: dict) -> dict:
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    H, K, ff = cfg["n_heads"], cfg["n_kv_heads"], cfg["d_ff"]
-    return {"q": (d, H * hd), "k": (d, K * hd), "v": (d, K * hd),
-            "o": (H * hd, d), "gate": (d, ff), "up": (d, ff), "down": (ff, d)}
-
-
-def adapter_leaves(cfg: dict, method: dict):
+def adapter_leaves(cfg: dict, method: dict, family=None):
     """(path under ``layers``, kind, per-layer shape, fan_in) of one
-    adapter of ``method`` ({"method", "rank", "targets", "n_prefix"})."""
-    dims = target_dims(cfg)
+    adapter of ``method`` ({"method", "rank", "targets", "n_prefix"}), on
+    the widths of the family's ``target_dims``."""
+    dims = harness.family(cfg, family).target_dims(cfg)
     out = []
     m = method["method"]
     if m == "lora":
@@ -186,14 +157,15 @@ def adapter_leaves(cfg: dict, method: dict):
 
 
 def make_bank(cfg: dict, method: dict, seed: int, bank: str, n: int,
-              dtype=jnp.float32):
+              dtype=jnp.float32, family=None):
     """``n`` adapters of one bank, stacked [n, layers, ...] under
     ``layers`` as the program's client banks are, from one jitted call."""
+    leaves = adapter_leaves(cfg, method, family)
 
     def build(key_data):
         key = jax.random.wrap_key_data(key_data)
         tree = {}
-        for path, kind, shape, fan in adapter_leaves(cfg, method):
+        for path, kind, shape, fan in leaves:
             name = f"adapter/{bank}/" + "/".join(path)
             _set(tree, ("layers",) + path, draw_indexed(
                 key, name, kind, (n, cfg["n_layers"]), shape, dtype, fan))

@@ -1,5 +1,6 @@
 """Each configuration file resolves to its published widths, cut only
-where ``reduced`` says, and builds the program's ModelConfig."""
+where ``reduced`` says and only as its model family allows
+(``bench/arch/<arch>.py``), and builds the program's ModelConfig."""
 import glob
 import json
 import os
@@ -8,32 +9,38 @@ import pytest
 
 from benchtiny import REPO
 
+from bench.harness import Registry
+
 FILES = sorted(glob.glob(os.path.join(REPO, "bench", "configs", "*.json")))
-# published key -> key of the configuration as run; widths are never cut
-WIDTHS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
-          "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
-          "vocab_size": "vocab", "head_dim": "head_dim",
-          "tie_word_embeddings": "tie_embeddings", "rope_theta": "rope_theta"}
+
+
+def check_published(c: dict, family):
+    """The configuration as run against its published config, by the
+    family's map of published keys (``PUBLISHED``), the sizes it implies
+    (``implied``) and the keys ``reduced`` may cut (``CUTS``, each only
+    downwards); widths are never cut."""
+    pub, m = c["published"], c["model"]
+    assert set(c["reduced"]) <= family.CUTS <= set(family.PUBLISHED)
+    for k, mk in family.PUBLISHED.items():
+        if k not in pub:
+            continue
+        if k in c["reduced"]:
+            assert 0 < m[mk] < pub[k], k
+        else:
+            assert m[mk] == pub[k], k
+    for mk, v in family.implied(pub).items():
+        assert m[mk] == v, mk
+    assert m["dtype"] == m["param_dtype"] == pub["torch_dtype"]
 
 
 @pytest.mark.parametrize("path", FILES, ids=os.path.basename)
 def test_published_widths(path):
     from repro.config import ModelConfig
     c = json.load(open(path))
-    pub, m = c["published"], c["model"]
+    m = c["model"]
     assert c["name"] == os.path.basename(path)[:-5] == m["name"]
     assert c["source"].startswith("https://")
-    for k, mk in WIDTHS.items():
-        if k in pub:
-            assert m[mk] == pub[k], k
-    if "head_dim" not in pub:
-        assert m["head_dim"] == pub["hidden_size"] // pub["num_attention_heads"]
-    if "num_hidden_layers" in c["reduced"]:
-        assert 0 < m["n_layers"] < pub["num_hidden_layers"]
-    else:
-        assert m["n_layers"] == pub["num_hidden_layers"]
-    assert set(c["reduced"]) <= {"num_hidden_layers"}
-    assert m["dtype"] == m["param_dtype"] == pub["torch_dtype"]
+    check_published(c, Registry([REPO]).arch(m["arch"]))
     ModelConfig(**m)
 
 

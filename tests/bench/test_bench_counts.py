@@ -8,8 +8,8 @@ from benchtiny import REPO
 
 from bench.harness import Registry
 
-CFG = {"d_model": 8, "head_dim": 2, "n_heads": 4, "n_kv_heads": 2,
-       "d_ff": 16, "vocab": 32, "n_layers": 3}
+CFG = {"arch": "dense", "d_model": 8, "head_dim": 2, "n_heads": 4,
+       "n_kv_heads": 2, "d_ff": 16, "vocab": 32, "n_layers": 3}
 PEAK = {"bf16_flops_per_s": 1e6, "hbm_bytes_per_s": 1e4}
 MIX = {"banks": [{"name": "lora", "method": "lora", "rank": 2,
                   "targets": ["q", "o"], "clients": 1},
